@@ -84,7 +84,9 @@ def validate(path: str) -> None:
         assert PLAN_KEYS <= set(p["plan"]), p["plan"]
         assert p["remat"] in ("full", "selective", "none"), p["remat"]
         assert p["wall_s_per_step"] > 0 and len(p["losses"]) >= 2, p
-        assert p["flops_per_step"] > 0 and 0.0 <= p["mfu"] <= 1.0, p
+        # mfu is None ("not measured") off-chip
+        assert p["flops_per_step"] > 0 and (
+            p["mfu"] is None or 0.0 <= p["mfu"] <= 1.0), p
 
     def arch_of(p):
         return p.get("arch", rec["config"]["arch"])

@@ -93,10 +93,11 @@ def telemetry_table(path: str) -> str:
         drift = "—" if ratio is None else (
             f"{ratio:.2f}x" + (" ⚠" if d.get("warn") else ""))
         loss = r.get("loss")
+        mfu = "not measured" if r["mfu"] is None else f"{r['mfu'] * 100:.2f}%"
         lines.append(
             f"| {r['step']} | {_fmt_s(r['wall_s'])} | "
             f"{r['tokens_per_s']:,.0f} | {r['tflops_per_device']:.3f} | "
-            f"{r['mfu']*100:.2f}% | "
+            f"{mfu} | "
             f"{'—' if loss is None else f'{loss:.4f}'} | {drift} |")
     return "\n".join(lines)
 

@@ -120,6 +120,9 @@ class V5eMachine(Machine):
         return 100e9  # 2 ICI links usable per axis hop
 
 
+# peaks: Google Cloud documentation, "TPU v5e" (per chip: 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s); matmul_eff and the link shares are model
+# assumptions, not published figures
 TPU_V5E = V5eMachine(
     name="tpu_v5e",
     gpus_per_node=256,           # one pod
@@ -131,6 +134,23 @@ TPU_V5E = V5eMachine(
     dp_contention_alpha=0.01,
     intranode_bw=100e9,          # ICI tier within a pod
 )
+
+# The one table of chip peaks, keyed by ``jax.devices()[0].device_kind``:
+# MFU denominators and on-chip drift anchors come from here.
+DEVICE_MACHINES: dict[str, Machine] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def machine_for_device_kind(kind: str) -> Machine:
+    """The :class:`Machine` whose peaks describe ``kind``; an unknown kind
+    raises — a utilization against an assumed peak is not a measurement."""
+    try:
+        return DEVICE_MACHINES[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates on record for device kind {kind!r}: add it to "
+            f"costmodel.DEVICE_MACHINES with its published source") from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -44,7 +44,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(
@@ -85,11 +84,11 @@ def pipeline_apply(
         outs = jnp.where(is_last, outs, 0)
         return jax.lax.psum(outs, pipe_axis)
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(pipe_axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -162,11 +161,11 @@ def pipeline_apply_interleaved(
             return a.reshape(v, p, *a.shape[1:]).swapaxes(0, 1)
         return jax.tree.map(re, stacked)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(pipe_axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def apply(stacked_stage_params, micro):

@@ -40,11 +40,7 @@ from repro.core import costmodel as cm
 
 SCHEMA = "repro.telemetry/1"
 
-# machines the --machine flag can name (MFU denominators / drift anchors)
-MACHINES: dict[str, cm.Machine] = {
-    "frontier": cm.FRONTIER,
-    "v5e": cm.TPU_V5E,
-}
+NOT_MEASURED = "not measured"
 
 # required keys per record kind — the contract ``validate_record`` enforces
 # and the CI telemetry job checks on real artifacts
@@ -98,6 +94,18 @@ def sanitize_record(rec: Mapping[str, Any], *,
     return clean(dict(rec))
 
 
+def device_machine() -> cm.Machine | None:
+    """Peak rates of the device this process runs on, looked up by
+    ``device_kind`` in ``costmodel.DEVICE_MACHINES``; ``None`` on the CPU,
+    where no utilization is measured.  An accelerator the table does not
+    know raises."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    return cm.machine_for_device_kind(dev.device_kind)
+
+
 def mfu(flops_per_step: float, step_time_s: float, n_devices: int,
         peak_flops: float) -> float:
     """Model-FLOPs utilization: analytic step FLOPs over what the machine
@@ -107,11 +115,12 @@ def mfu(flops_per_step: float, step_time_s: float, n_devices: int,
 
 
 def step_fields(cfg, global_batch: int, seq_len: int, wall_s: float,
-                n_devices: int, machine: cm.Machine | str = "frontier") -> dict:
+                n_devices: int, machine: cm.Machine | None = None) -> dict:
     """Throughput fields for one measured step — the fragment the BENCH_*
     writers merge into their point records so bench artifacts share the
-    telemetry schema's accounting."""
-    machine = MACHINES[machine] if isinstance(machine, str) else machine
+    telemetry schema's accounting.  ``machine`` defaults to this process's
+    device (:func:`device_machine`); with none, ``mfu`` is ``None``."""
+    machine = machine or device_machine()
     flops = cm.train_step_flops(cfg, global_batch, seq_len).total
     tokens = global_batch * seq_len
     return {
@@ -119,8 +128,9 @@ def step_fields(cfg, global_batch: int, seq_len: int, wall_s: float,
         "flops_per_step": flops,
         "tflops_per_device": (flops / (wall_s * max(n_devices, 1)) / 1e12
                               if wall_s > 0 else 0.0),
-        "mfu": mfu(flops, wall_s, n_devices, machine.peak_flops),
-        "machine": machine.name,
+        "mfu": (mfu(flops, wall_s, n_devices, machine.peak_flops)
+                if machine else None),
+        "machine": machine.name if machine else None,
     }
 
 
@@ -190,24 +200,28 @@ class Telemetry:
     """Per-run recorder: one compile record, then one record per step.
 
     ``cfg`` is the ``ModelConfig`` actually trained, ``plan`` the
-    ``ParallelPlan`` (or duck-typed equivalent).  The analytic FLOPs and
+    ``ParallelPlan`` (or duck-typed equivalent).  ``machine`` (default: the
+    device's own, :func:`device_machine`) supplies the MFU peak and the
+    drift anchor; without one — on the CPU — ``mfu`` is recorded as
+    ``None`` ("not measured") and the anchor is the costmodel's calibrated
+    Frontier machine.  The analytic FLOPs and
     the costmodel prediction are computed once here; each
     :meth:`step` call only does O(1) bookkeeping on top of the metrics the
     executor already returns.
     """
 
     def __init__(self, cfg, plan, global_batch: int, seq_len: int, *,
-                 machine: cm.Machine | str = "frontier",
+                 machine: cm.Machine | None = None,
                  jsonl: str | None = None,
                  drift_threshold: float = 10.0, drift_window: int = 20):
         self.cfg, self.plan = cfg, plan
         self.global_batch, self.seq_len = global_batch, seq_len
-        self.machine = (MACHINES[machine] if isinstance(machine, str)
-                        else machine)
+        self.machine = machine or device_machine()
         self.flops = cm.train_step_flops(cfg, global_batch, seq_len)
         try:
             self.prediction = cm.predict_step(cfg, plan, global_batch,
-                                              seq_len, self.machine)
+                                              seq_len,
+                                              self.machine or cm.FRONTIER)
         except Exception:                   # exotic plan the model can't price
             self.prediction = None
         self.drift = DriftMonitor(threshold=drift_threshold,
@@ -236,8 +250,8 @@ class Telemetry:
             "devices": jax.device_count(),
             "backend": jax.default_backend(),
             "kernels_interpret_mode": jax.default_backend() == "cpu",
-            "machine": self.machine.name,
-            "peak_flops": self.machine.peak_flops,
+            "machine": self.machine.name if self.machine else None,
+            "peak_flops": self.machine.peak_flops if self.machine else None,
             "flops_per_step": self.flops.total,
             "flops_breakdown": {"matmul": self.flops.matmul,
                                 "attn": self.flops.attn,
@@ -282,8 +296,7 @@ class Telemetry:
             "flops_per_step": self.flops.total,
             "tflops_per_device": (self.flops.total / (wall_s * n_dev) / 1e12
                                   if wall_s > 0 else 0.0),
-            "mfu": mfu(self.flops.total, wall_s, n_dev,
-                       self.machine.peak_flops),
+            "mfu": self.mfu(self.flops.total, wall_s),
             "predicted": self._predicted_block(),
         }
         for k in ("loss", "moe_aux", "moe_drop", "grad_norm", "loss_scale",
@@ -294,6 +307,13 @@ class Telemetry:
                        if self.prediction is not None else 0.0)
         rec["drift"] = self.drift.update(wall_s, predicted_s)
         return self._emit(rec)
+
+    def mfu(self, flops: float, wall_s: float) -> float | None:
+        """MFU against this run's device peak; ``None`` without one."""
+        if self.machine is None:
+            return None
+        return mfu(flops, wall_s, self.plan.n_devices,
+                   self.machine.peak_flops)
 
     def _emit(self, rec: dict) -> dict:
         rec = sanitize_record(rec)
@@ -316,9 +336,9 @@ class Telemetry:
                 f"scale {rec['loss_scale']:.0f} "
                 f"{tok_s:,.0f} tok/s")
         if with_mfu:
-            w_mfu = mfu(self.flops.total * len(walls), dt,
-                        self.plan.n_devices, self.machine.peak_flops)
-            line += f" mfu {100.0 * w_mfu:.2f}%"
+            w_mfu = self.mfu(self.flops.total * len(walls), dt)
+            line += (f" mfu {NOT_MEASURED}" if w_mfu is None
+                     else f" mfu {100.0 * w_mfu:.2f}%")
         return line
 
     def close(self) -> None:
@@ -401,7 +421,7 @@ def validate_record(rec: Mapping[str, Any]) -> None:
         for k in ("step_time_ratio", "rolling_ratio", "warn", "threshold"):
             if k not in d:
                 raise ValueError(f"drift block missing {k!r}")
-        if not (0.0 <= rec["mfu"] <= 1.0):
+        if rec["mfu"] is not None and not (0.0 <= rec["mfu"] <= 1.0):
             raise ValueError(f"mfu {rec['mfu']} outside [0, 1]")
     if kind == "compile":
         if rec["kernels_interpret_mode"] != (rec["backend"] == "cpu"):

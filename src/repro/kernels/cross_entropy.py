@@ -26,12 +26,14 @@ from repro.kernels.tiling import fit_block
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_N = 256
-DEFAULT_BLOCK_V = 2048
+DEFAULT_BLOCK_V = 512   # (d, block_v) weight blocks fit scoped VMEM at d ~ 2k-4k
 NEG_INF = -1e30
 
 
 def _ce_kernel(h_ref, w_ref, y_ref, lse_ref, ylogit_ref,
                m_ref, s_ref, yl_ref, *, block_v: int, nv: int, valid_vocab: int):
+    # per-row stats are (bn, 1) columns: a 1-D (bn,) block breaks Mosaic's
+    # (8, 128) tiling unless bn is a multiple of 128
     iv = pl.program_id(1)
 
     @pl.when(iv == 0)
@@ -40,26 +42,27 @@ def _ce_kernel(h_ref, w_ref, y_ref, lse_ref, ylogit_ref,
         s_ref[...] = jnp.zeros_like(s_ref)
         yl_ref[...] = jnp.full_like(yl_ref, NEG_INF)
 
-    h = h_ref[...].astype(jnp.float32)            # (bn, d)
-    w = w_ref[...].astype(jnp.float32)            # (d, bv)
+    # storage-dtype operands, fp32 accumulation (see swiglu.py)
     logits = jax.lax.dot_general(
-        h, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     # mask padded vocab columns
     col = iv * block_v + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     logits = jnp.where(col < valid_vocab, logits, NEG_INF)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    s_ref[...] = s_ref[...] * corr + jnp.sum(jnp.exp(logits - m_new[:, None]), axis=1)
+    s_ref[...] = s_ref[...] * corr + jnp.sum(jnp.exp(logits - m_new), axis=1,
+                                             keepdims=True)
     m_ref[...] = m_new
 
-    # gather this block's label logits
-    y = y_ref[...]                                 # (bn,)
+    # this block's label logits: an iota-compare select (Mosaic has no
+    # in-kernel gather)
+    y = y_ref[...]                                 # (bn, 1)
     in_block = (y >= iv * block_v) & (y < (iv + 1) * block_v)
-    local = jnp.clip(y - iv * block_v, 0, block_v - 1)
-    picked = jnp.take_along_axis(logits, local[:, None], axis=1)[:, 0]
+    picked = jnp.sum(jnp.where(col == y, logits, 0.0), axis=1, keepdims=True)
     yl_ref[...] = jnp.where(in_block, picked, yl_ref[...])
 
     @pl.when(iv == nv - 1)
@@ -77,33 +80,40 @@ def ce_logsumexp_pallas(h: jax.Array, w: jax.Array, labels: jax.Array, *,
     N, d = h.shape
     V = w.shape[1]
     valid_vocab = valid_vocab or V
-    block_n = fit_block(block_n, N)
-    block_v = fit_block(block_v, V)
-    nn, nv = N // block_n, V // block_v
-    return pl.pallas_call(
+    rows_block = fit_block(block_n, N, 8)
+    if rows_block > block_n:
+        # no 8-aligned divisor (e.g. B * (T - 1) shifted-label rows): pad
+        # to whole blocks rather than make one VMEM-sized block of all N
+        rows_block = max(8, block_n - block_n % 8)
+        pad = -N % rows_block
+        h = jnp.pad(h, ((0, pad), (0, 0)))
+        labels = jnp.pad(labels, (0, pad))
+    block_n, rows = rows_block, h.shape[0]
+    block_v = fit_block(block_v, V, 128)
+    nn, nv = rows // block_n, V // block_v
+    col_spec = pl.BlockSpec((block_n, 1), lambda i, j: (i, 0))
+    lse, ylogit = pl.pallas_call(
         functools.partial(_ce_kernel, block_v=block_v, nv=nv,
                           valid_vocab=valid_vocab),
         grid=(nn, nv),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, block_v), lambda i, j: (0, j)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
+            col_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-        ],
+        out_specs=[col_spec, col_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((N,), jnp.float32),
-            jax.ShapeDtypeStruct((N,), jnp.float32),
+            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_n,), jnp.float32),
-            pltpu.VMEM((block_n,), jnp.float32),
-            pltpu.VMEM((block_n,), jnp.float32),
+            pltpu.VMEM((block_n, 1), jnp.float32),
+            pltpu.VMEM((block_n, 1), jnp.float32),
+            pltpu.VMEM((block_n, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(h, w, labels)
+    )(h, w, labels.reshape(rows, 1))
+    return lse[:N, 0], ylogit[:N, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

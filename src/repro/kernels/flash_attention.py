@@ -91,12 +91,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             mask = wmask if mask is None else jnp.logical_and(mask, wmask)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                           # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -104,7 +104,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finish():
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe_l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(safe_l)
 
 
@@ -142,17 +142,17 @@ def flash_attention_fwd(q, k, v, *, causal, window, q_offset,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             # VMEM online-softmax state carried across the nk loop
             pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
@@ -205,10 +205,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             mask = w if mask is None else jnp.logical_and(mask, w)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         if tcap is not None:
             ds = ds * (1.0 - tcap * tcap)   # d tanh(s/c)*c / ds
         acc_ref[...] += jax.lax.dot_general(
@@ -268,12 +268,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             mask = w if mask is None else jnp.logical_and(mask, w)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                     # (bq, bk)
+        p = jnp.exp(s - lse)                              # (bq, bk)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         if tcap is not None:
             ds = ds * (1.0 - tcap * tcap)   # d tanh(s/c)*c / ds
         dk_acc[...] += jax.lax.dot_general(
@@ -295,7 +295,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, q_offset,
     block_k = min(block_k, Skv)
     nq, nk = Sq // block_q, Skv // block_k
     scale = 1.0 / np.sqrt(hd)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+                    keepdims=True)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -309,8 +310,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, q_offset,
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda b, h, i, j: (b, h // g, j, 0)),
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -335,10 +336,10 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, q_offset,
                          lambda b, h, j, gg, i: (b, h, j, 0)),
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda b, h, j, gg, i: (b, h * g + gg, i, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, h, j, gg, i: (b, h * g + gg, i)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, h, j, gg, i: (b, h * g + gg, i)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, j, gg, i: (b, h * g + gg, i, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, j, gg, i: (b, h * g + gg, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, hd),

@@ -31,9 +31,8 @@ def _gelu_f32(a):
 
 
 def _gelu_mlp_kernel(x_ref, w1_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)
-    a = jax.lax.dot_general(x, w1_ref[...].astype(jnp.float32),
-                            (((1,), (0,)), ((), ())),
+    # storage-dtype operands, fp32 accumulation (see swiglu.py)
+    a = jax.lax.dot_general(x_ref[...], w1_ref[...], (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     o_ref[...] = _gelu_f32(a).astype(o_ref.dtype)
 
@@ -43,7 +42,7 @@ def gelu_mlp_fwd_pallas(x2d: jax.Array, w1: jax.Array, *,
                         interpret: bool) -> jax.Array:
     N, d = x2d.shape
     F = w1.shape[1]
-    bn, bf = fit_block(block_n, N), fit_block(block_f, F)
+    bn, bf = fit_block(block_n, N, 8), fit_block(block_f, F, 128)
     return pl.pallas_call(
         _gelu_mlp_kernel,
         grid=(N // bn, F // bf),

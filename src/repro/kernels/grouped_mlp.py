@@ -1,6 +1,7 @@
-"""Fused grouped expert MLP as a Pallas kernel: each expert's full
-slot batch runs w1/[w3]/w2 in one VMEM-resident pass over the expert-major
-(E, N, d) layout (N = groups * capacity slots), with the slot validity
+"""Fused grouped expert MLP as a Pallas kernel: each block of an expert's
+slots runs w1/[w3]/w2 over the expert-major (E, N, d) layout (N = groups *
+capacity slots), streaming d_ff blocks of the weights past an fp32 VMEM
+accumulator so the hidden activation never reaches HBM, with the slot validity
 mask applied in-kernel — padded capacity slots contribute exactly zero to
 the output and to every weight gradient, matching the reference semantics
 where they cost no FLOPs.
@@ -18,56 +19,88 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tiling import fit_block
 
 DEFAULT_BLOCK_N = 256
+DEFAULT_BLOCK_F = 256
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _swiglu_kernel(x_ref, w1_ref, w3_ref, w2_ref, m_ref, o_ref):
-    m = m_ref[0].astype(jnp.float32)[:, None]
-    x = x_ref[0].astype(jnp.float32) * m
-    dot = functools.partial(jax.lax.dot_general,
-                            dimension_numbers=(((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    a = dot(x, w1_ref[0].astype(jnp.float32))
-    b = dot(x, w3_ref[0].astype(jnp.float32))
-    h = a * jax.nn.sigmoid(a) * b
-    o_ref[0] = (dot(h, w2_ref[0].astype(jnp.float32)) * m).astype(o_ref.dtype)
+def _accumulate(h, w2_ref, m, o_ref, acc_ref):
+    """acc += h @ w2 over the d_ff blocks; the last block writes the masked
+    output.  The activation is elementwise in d_ff, so splitting d_ff into
+    blocks and summing their down-projections is exact."""
+    f = pl.program_id(2)
+
+    @pl.when(f == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += jax.lax.dot_general(
+        h.astype(w2_ref.dtype), w2_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(f == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[...] * m).astype(o_ref.dtype)
 
 
-def _gelu_kernel(x_ref, w1_ref, w2_ref, m_ref, o_ref):
-    m = m_ref[0].astype(jnp.float32)[:, None]
-    x = x_ref[0].astype(jnp.float32) * m
-    dot = functools.partial(jax.lax.dot_general,
-                            dimension_numbers=(((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    h = jax.nn.gelu(dot(x, w1_ref[0].astype(jnp.float32)), approximate=True)
-    o_ref[0] = (dot(h, w2_ref[0].astype(jnp.float32)) * m).astype(o_ref.dtype)
+def _up(x, w_ref):
+    return jax.lax.dot_general(x, w_ref[0], (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _swiglu_kernel(x_ref, w1_ref, w3_ref, w2_ref, m_ref, o_ref, acc_ref):
+    m = m_ref[0]                                     # (bn, 1)
+    x = x_ref[0] * m.astype(x_ref.dtype)
+    a = _up(x, w1_ref)
+    h = a * jax.nn.sigmoid(a) * _up(x, w3_ref)
+    _accumulate(h, w2_ref, m, o_ref, acc_ref)
+
+
+def _gelu_kernel(x_ref, w1_ref, w2_ref, m_ref, o_ref, acc_ref):
+    m = m_ref[0]
+    x = x_ref[0] * m.astype(x_ref.dtype)
+    h = jax.nn.gelu(_up(x, w1_ref), approximate=True)
+    _accumulate(h, w2_ref, m, o_ref, acc_ref)
 
 
 def _fwd_pallas(x, w1, w3, w2, mask, *, block_n: int, interpret: bool):
+    # grid (E, N blocks, d_ff blocks), d_ff minor-most: the (bn, d) fp32
+    # accumulator stays in VMEM while (d, bf)/(bf, d) weight blocks stream —
+    # whole (d, F) expert weights would overrun VMEM at arctic widths
     E, N, d = x.shape
     F = w1.shape[-1]
-    bn = fit_block(block_n, N)
-    xm_spec = [pl.BlockSpec((1, bn, d), lambda e, i: (e, i, 0))]
-    w_in = pl.BlockSpec((1, d, F), lambda e, i: (e, 0, 0))
-    w_out = pl.BlockSpec((1, F, d), lambda e, i: (e, 0, 0))
-    m_spec = pl.BlockSpec((1, bn), lambda e, i: (e, i))
+    bn = fit_block(block_n, N, 8)
+    bf = fit_block(DEFAULT_BLOCK_F, F, 128)
+    xm_spec = [pl.BlockSpec((1, bn, d), lambda e, i, f: (e, i, 0))]
+    w_in = pl.BlockSpec((1, d, bf), lambda e, i, f: (e, 0, f))
+    w_out = pl.BlockSpec((1, bf, d), lambda e, i, f: (e, f, 0))
+    # the slot mask rides as an (E, N, 1) column: an (E, N) operand would
+    # need a (1, bn) block, which breaks the (8, 128) tiling
+    m_spec = pl.BlockSpec((1, bn, 1), lambda e, i, f: (e, i, 0))
+    mask3 = mask.astype(jnp.float32)[..., None]
     if w3 is not None:
         kernel, in_specs, args = (_swiglu_kernel,
                                   xm_spec + [w_in, w_in, w_out, m_spec],
-                                  (x, w1, w3, w2, mask))
+                                  (x, w1, w3, w2, mask3))
     else:
         kernel, in_specs, args = (_gelu_kernel,
                                   xm_spec + [w_in, w_out, m_spec],
-                                  (x, w1, w2, mask))
+                                  (x, w1, w2, mask3))
     return pl.pallas_call(
         kernel,
-        grid=(E, N // bn),
+        grid=(E, N // bn, F // bf),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bn, d), lambda e, i: (e, i, 0)),
+        out_specs=pl.BlockSpec((1, bn, d), lambda e, i, f: (e, i, 0)),
         out_shape=jax.ShapeDtypeStruct((E, N, d), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
+        # at d_model ~7k the double-buffered x/weight/output blocks plus the
+        # accumulator need ~40 MiB: above the 16 MiB default scoped limit,
+        # well inside a v5e core's 128 MiB of VMEM
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(*args)
 

@@ -56,7 +56,7 @@ def layernorm(x, w, b, eps=1e-5, block_rows=DEFAULT_BLOCK_ROWS,
     shape = x.shape
     x2d = x.reshape(-1, shape[-1])
     out = layernorm_fwd_pallas(x2d, w, b, eps=eps,
-                               block_rows=fit_block(block_rows, x2d.shape[0]),
+                               block_rows=fit_block(block_rows, x2d.shape[0], 8),
                                interpret=interpret)
     return out.reshape(shape)
 

@@ -46,8 +46,8 @@ def flash_attention(
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    bq = fit_block(block_q, Sq)
-    bk = fit_block(block_k, Skv)
+    bq = fit_block(block_q, Sq, 8)
+    bk = fit_block(block_k, Skv, 8)
     out = fa.flash_attention(qt, kt, vt, causal, sliding_window, q_offset,
                              bq, bk, interpret, softcap)
     return out.transpose(0, 2, 1, 3)

@@ -49,7 +49,7 @@ def rmsnorm(x, w, eps=1e-5, block_rows=DEFAULT_BLOCK_ROWS, interpret=False):
     """x: (..., d); w: (d,)."""
     shape = x.shape
     x2d = x.reshape(-1, shape[-1])
-    out = rmsnorm_fwd_pallas(x2d, w, eps=eps, block_rows=fit_block(block_rows, x2d.shape[0]),
+    out = rmsnorm_fwd_pallas(x2d, w, eps=eps, block_rows=fit_block(block_rows, x2d.shape[0], 8),
                              interpret=interpret)
     return out.reshape(shape)
 

@@ -22,12 +22,12 @@ DEFAULT_BLOCK_F = 512
 
 
 def _swiglu_kernel(x_ref, w1_ref, w3_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)
-    a = jax.lax.dot_general(x, w1_ref[...].astype(jnp.float32),
-                            (((1,), (0,)), ((), ())),
+    # operands stay in their storage dtype (the MXU accumulates in fp32):
+    # fp32 copies of the x and weight blocks would overrun scoped VMEM
+    x = x_ref[...]
+    a = jax.lax.dot_general(x, w1_ref[...], (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    b = jax.lax.dot_general(x, w3_ref[...].astype(jnp.float32),
-                            (((1,), (0,)), ((), ())),
+    b = jax.lax.dot_general(x, w3_ref[...], (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     o_ref[...] = (a * jax.nn.sigmoid(a) * b).astype(o_ref.dtype)
 
@@ -37,7 +37,7 @@ def swiglu_fwd_pallas(x2d: jax.Array, w1: jax.Array, w3: jax.Array, *,
                       interpret: bool) -> jax.Array:
     N, d = x2d.shape
     F = w1.shape[1]
-    bn, bf = fit_block(block_n, N), fit_block(block_f, F)
+    bn, bf = fit_block(block_n, N, 8), fit_block(block_f, F, 128)
     return pl.pallas_call(
         _swiglu_kernel,
         grid=(N // bn, F // bf),

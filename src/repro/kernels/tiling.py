@@ -1,7 +1,11 @@
 """Shared block/chunk-fitting helpers for the Pallas kernels.
 
 ``fit_block`` picks the largest block size <= ``block`` that divides ``n``
-(Pallas grids need exact tiling).  ``pick_chunk`` is the shared chunk-size
+(Pallas grids need exact tiling) and is a multiple of ``align``: Mosaic
+accepts a block whose last two dims are multiples of (8, 128) or equal to
+the array's dims, so sublane (row) dims pass ``align=8`` and lane (minor)
+dims ``align=128``; when no aligned divisor exists the whole dim is one
+block.  ``pick_chunk`` is the shared chunk-size
 heuristic of the two chunked recurrent scans (mamba2 SSD, rwkv wkv): the
 largest power-of-two chunk <= ``target`` dividing T — one definition used
 by both the jnp reference paths in ``models/{ssm,rwkv}.py`` and the Pallas
@@ -17,11 +21,13 @@ SSD_CHUNK = 128
 WKV_CHUNK = 32
 
 
-def fit_block(block: int, n: int) -> int:
-    b = min(block, n)
-    while n % b != 0:
-        b -= 1
-    return b
+def fit_block(block: int, n: int, align: int = 1) -> int:
+    if n <= block:
+        return n
+    for b in range(block - block % align, 0, -align):
+        if n % b == 0:
+            return b
+    return n
 
 
 def pick_chunk(T: int, target: int) -> int:

@@ -202,8 +202,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool,
         t_compile = time.time() - t0
 
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # jax 0.4.x: list of per-program dicts
-            cost = cost[0] if cost else {}
         try:
             ma = compiled.memory_analysis()
             mem = {

@@ -17,24 +17,12 @@ touches jax device state (the dry-run sets XLA_FLAGS before first jax use).
 from __future__ import annotations
 
 import jax
-
-# Version-compat shim: jax >= 0.5 exposes jax.sharding.AxisType and
-# jax.make_mesh(..., axis_types=...); jax 0.4.x has neither.  All meshes in
-# this repo are Auto-typed, so falling back to the plain signature is exact.
-try:  # pragma: no cover - exercised implicitly by whichever jax is installed
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax < 0.5
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if _AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(_AxisType.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    # every mesh in this repo is Auto-typed: GSPMD propagates shardings
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import ASSIGNED, PAPER, get_config
 from repro.core.telemetry import JsonlSink
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.runtime.serve_engine import Request, ServeEngine
 
@@ -96,6 +97,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-jsonl", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,9 +1,10 @@
 """Unified training launcher: one surface for any (dp, tp, pp) plan.
 
-On a real v5e deployment each host runs this under the TPU runtime and
-``jax.distributed.initialize()`` wires the pod slice together; on this CPU
-container it drives the same code path on a single device (or virtual
-devices via XLA_FLAGS), with reduced configs for smoke-scale runs.
+On a v5e deployment each host runs this under the TPU runtime and
+``jax.distributed.initialize()`` wires the pod slice together; on a CPU it
+drives the same code path on one device (or virtual devices via
+XLA_FLAGS), with reduced configs for smoke-scale runs.  :func:`train` is
+the same run as a function (``chip_smoke.py`` calls it in-process).
 
   PYTHONPATH=src python -m repro.launch.train --arch yi-6b --reduced \
       --steps 100 --global-batch 8 --seq-len 128 --ckpt-dir /tmp/ckpt
@@ -18,6 +19,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +29,9 @@ from repro.checkpointing import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import ASSIGNED, PAPER, get_config
 from repro.core import telemetry
 from repro.data import SyntheticCorpus, make_batch_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_for_plan
+from repro.models.common import ModelConfig
 from repro.models.model import Model
 from repro.optim import AdamWConfig, cosine_schedule
 from repro.runtime.train_loop import (ParallelPlan, init_train_state,
@@ -69,7 +73,7 @@ def parse_plan(args, n_devices: int) -> ParallelPlan:
     return plan
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ASSIGNED + PAPER), default="yi-6b")
     ap.add_argument("--reduced", action="store_true",
@@ -88,7 +92,8 @@ def main() -> None:
                          "save everything (fastest, most memory)")
     ap.add_argument("--kernels", action="store_true",
                     help="route norm/MLP-gate/attention/CE through the fused "
-                         "Pallas kernels (interpret-mode on CPU)")
+                         "Pallas kernels (Mosaic on a TPU, interpret mode on "
+                         "the CPU)")
     ap.add_argument("--rules", choices=["megatron_tp", "fsdp", "dp_only", "tp_only"],
                     default="megatron_tp")
     ap.add_argument("--zero", type=int, choices=[0, 1, 2, 3], default=None,
@@ -139,24 +144,48 @@ def main() -> None:
                     help="write a Chrome-trace/Perfetto JSON of the "
                          "pipeline schedule against measured step times "
                          "(analysis/trace.py; view at chrome://tracing)")
-    ap.add_argument("--machine", choices=sorted(telemetry.MACHINES),
-                    default="frontier",
-                    help="MFU denominator / costmodel drift anchor")
     ap.add_argument("--drift-threshold", type=float, default=10.0,
                     help="warn when the rolling measured/predicted "
                          "step-time ratio leaves [1/x, x]")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_config(args.arch)
-    if args.reduced:
+
+def resolve_config(arch: str, *, reduced: bool = False,
+                   layers: int | None = None, ep: int = 1) -> ModelConfig:
+    """The architecture's config, optionally reduced and/or with its layer
+    count overridden (depth is the one dimension cut to fit a chip)."""
+    cfg = get_config(arch)
+    if reduced:
         # ep-aware clamp: the reduced expert count must stay divisible
         # by the plan's expert ways (no-op for ep=1 / non-moe families)
-        overrides = {"n_layers": args.layers} if args.layers else {}
-        cfg = cfg.reduced(ep=args.ep, **overrides)
-    elif args.layers:
-        cfg = dataclasses.replace(cfg, n_layers=args.layers)
-    n_dev = jax.device_count()
-    plan = parse_plan(args, n_dev)
+        overrides = {"n_layers": layers} if layers else {}
+        return cfg.reduced(ep=ep, **overrides)
+    if layers:
+        return dataclasses.replace(cfg, n_layers=layers)
+    return cfg
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """What one :func:`train` call measured: per-step losses and walls
+    (blocked on the step's outputs), AOT compile seconds, the compiled step
+    and the final state."""
+    losses: list[float]
+    step_walls: list[float]
+    compile_s: float
+    compiled: Any
+    state: dict
+    telemetry: telemetry.Telemetry
+
+
+def train(cfg: ModelConfig, plan: ParallelPlan, *, steps: int,
+          global_batch: int, seq_len: int, lr: float = 1e-3, seed: int = 0,
+          ckpt_dir: str | None = None, ckpt_every: int = 50,
+          log_every: int = 10, log_jsonl: str | None = None,
+          trace: str | None = None,
+          drift_threshold: float = 10.0) -> TrainRun:
+    """Train ``cfg`` under ``plan`` on this process's devices for ``steps``
+    optimizer steps of synthetic data from ``seed``."""
     # --kernels is fully fused on every family now: rmsnorm + layernorm,
     # swiglu + gelu gates, flash attention (softcap native), CE, and the
     # grouped expert MLP (kernels/grouped_mlp.py) for MoE — no fallbacks
@@ -172,38 +201,38 @@ def main() -> None:
           f"precision={plan.precision} remat={plan.remat} "
           f"kernels={plan.kernels}{comm_s}")
 
-    model = Model(cfg, jnp.float32 if args.precision == "fp32" else jnp.bfloat16)
-    opt = AdamWConfig(lr=cosine_schedule(args.lr, 10, args.steps))
-    state = init_train_state(model, jax.random.PRNGKey(args.seed), opt, plan)
+    model = Model(cfg, jnp.float32 if plan.precision == "fp32" else jnp.bfloat16)
+    opt = AdamWConfig(lr=cosine_schedule(lr, 10, steps))
+    state = init_train_state(model, jax.random.PRNGKey(seed), opt, plan,
+                             mesh=mesh)
     start = 0
-    if args.ckpt_dir and (s := latest_step(args.ckpt_dir)) is not None:
+    if ckpt_dir and (s := latest_step(ckpt_dir)) is not None:
         like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
-        state = restore_checkpoint(args.ckpt_dir, s, like)
+        state = restore_checkpoint(ckpt_dir, s, like)
         start = s
-        print(f"restored step {s} from {args.ckpt_dir}")
+        print(f"restored step {s} from {ckpt_dir}")
 
-    step_fn = jit_train_step(model, opt, plan, mesh, args.global_batch, args.seq_len)
+    step_fn = jit_train_step(model, opt, plan, mesh, global_batch, seq_len)
     extra = {}
     if cfg.family == "encdec":
         extra["frames"] = ((cfg.enc_seq_len, cfg.frontend_dim), "float32")
     if cfg.family == "vlm":
         extra["patches"] = ((cfg.num_patches, cfg.frontend_dim), "float32")
     it = make_batch_iterator(
-        SyntheticCorpus(vocab_size=cfg.vocab_size, seed=args.seed),
-        seq_len=args.seq_len, global_batch=args.global_batch,
+        SyntheticCorpus(vocab_size=cfg.vocab_size, seed=seed),
+        seq_len=seq_len, global_batch=global_batch,
         extra_specs={k: (sh, np.dtype(dt)) for k, (sh, dt) in extra.items()} or None)
 
     # telemetry rides every run (records stay in memory unless --log-jsonl);
     # the MFU console suffix appears only when telemetry output was asked
     # for, keeping the documented default step-line format byte-identical
-    tele_on = bool(args.log_jsonl or args.trace)
+    tele_on = bool(log_jsonl or trace)
     tele = telemetry.Telemetry(
-        cfg, plan, args.global_batch, args.seq_len, machine=args.machine,
-        jsonl=args.log_jsonl,
+        cfg, plan, global_batch, seq_len, jsonl=log_jsonl,
         # the drift warning only fires on runs that asked for telemetry
         # output — a smoke run on this CPU container always drifts hugely
         # and the default console should stay as quiet as before
-        drift_threshold=args.drift_threshold if tele_on else float("inf"))
+        drift_threshold=drift_threshold if tele_on else float("inf"))
 
     # AOT compile: one .lower().compile() captures the measured collective
     # payload bytes + XLA's peak estimate for the compile record, and the
@@ -211,35 +240,54 @@ def main() -> None:
     t0 = time.time()
     batch = next(it)
     compiled = step_fn.lower(state, batch).compile()
+    compile_s = time.time() - t0
     tele.record_compile(
         compiled, state_bytes=train_state_bytes(model, mesh, plan),
-        compile_s=time.time() - t0)
+        compile_s=compile_s)
 
-    for i in range(start, args.steps):
+    losses = []
+    for i in range(start, steps):
         (state, metrics), wall = telemetry.timed_call(compiled, state, batch)
         rec = tele.step(i + 1, wall, metrics)
-        if (i + 1) % args.log_every == 0:
-            print(tele.console_line(rec, window=args.log_every,
-                                    with_mfu=tele_on))
-        if args.ckpt_dir and (i + 1) % args.ckpt_every == 0:
-            save_checkpoint(args.ckpt_dir, i + 1, state)
+        losses.append(float(metrics["loss"]))
+        if (i + 1) % log_every == 0:
+            print(tele.console_line(rec, window=log_every, with_mfu=tele_on))
+        if ckpt_dir and (i + 1) % ckpt_every == 0:
+            save_checkpoint(ckpt_dir, i + 1, state)
         batch = next(it)
 
-    if args.trace:
+    if trace:
         from repro.analysis import trace as trace_mod
         tr = trace_mod.build_trace(
             plan.pp, plan.gas, plan.virtual_stages, tele.step_walls,
             meta={"arch": cfg.name, "plan": telemetry.plan_dict(plan)})
-        trace_mod.write_trace(tr, args.trace)
-        print(f"wrote pipeline trace to {args.trace} "
+        trace_mod.write_trace(tr, trace)
+        print(f"wrote pipeline trace to {trace} "
               f"({len(tr['traceEvents'])} events)")
     tele.close()
-    walls = tele.step_walls
+    return TrainRun(losses=losses, step_walls=list(tele.step_walls),
+                    compile_s=compile_s, compiled=compiled, state=state,
+                    telemetry=tele)
+
+
+def main(argv: list[str] | None = None) -> None:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg = resolve_config(args.arch, reduced=args.reduced, layers=args.layers,
+                         ep=args.ep)
+    plan = parse_plan(args, jax.device_count())
+    run = train(cfg, plan, steps=args.steps, global_batch=args.global_batch,
+                seq_len=args.seq_len, lr=args.lr, seed=args.seed,
+                ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
+                log_every=args.log_every, log_jsonl=args.log_jsonl,
+                trace=args.trace, drift_threshold=args.drift_threshold)
+    walls = run.step_walls
     if walls:
         med = sorted(walls)[len(walls) // 2]
-        print(f"done. median step {med * 1e3:.1f} ms, "
-              f"mfu {100.0 * telemetry.mfu(tele.flops.total, med, plan.n_devices, tele.machine.peak_flops):.2f}% "
-              f"({tele.machine.name})")
+        u = run.telemetry.mfu(run.telemetry.flops.total, med)
+        mfu_s = (f"mfu {telemetry.NOT_MEASURED}" if u is None else
+                 f"mfu {100.0 * u:.2f}% ({run.telemetry.machine.name})")
+        print(f"done. median step {med * 1e3:.1f} ms, {mfu_s}")
     else:
         print("done.")
 

@@ -106,7 +106,7 @@ def greedy_generate(model: Model, params: Any, prompt: jax.Array,
     pb: dict[str, Any] = {"tokens": prompt}
     if extras:
         pb.update(extras)
-    logits, cache = model.prefill(params, pb, cache_len)
+    logits, cache = build_prefill(model, cache_len)(params, pb)
     tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
     decode = build_decode_step(model)
     db_extra: dict[str, Any] = {}

@@ -277,14 +277,24 @@ def batch_shardings(cfg: ModelConfig, global_batch: int, seq_len: int,
 
 
 def init_train_state(model: Model, key: jax.Array, opt_cfg: AdamWConfig,
-                     plan: ParallelPlan) -> dict:
-    params = model.init(key)
-    return {
-        "params": params,
-        "opt": adamw_init(params),
-        "loss_scale": prec.init_loss_scale(plan.precision == "fp16"),
-        "step": jnp.int32(0),
-    }
+                     plan: ParallelPlan, mesh: Mesh | None = None) -> dict:
+    """Fresh train state.  With ``mesh`` the state is built inside one jit
+    whose outputs already carry the plan's shardings, so no device ever
+    holds the whole unsharded state; without it, arrays are built eagerly
+    on the default device (small and single-device runs)."""
+    def init():
+        params = model.init(key)
+        return {
+            "params": params,
+            "opt": adamw_init(params),
+            "loss_scale": prec.init_loss_scale(plan.precision == "fp16"),
+            "step": jnp.int32(0),
+        }
+
+    if mesh is None:
+        return init()
+    return jax.jit(init, out_shardings=train_state_shardings(
+        model, mesh, plan))()
 
 
 def build_train_step(model: Model, opt_cfg: AdamWConfig, plan: ParallelPlan,

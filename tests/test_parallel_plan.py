@@ -160,3 +160,42 @@ def test_parallel_plan_validation():
     # pp>1 plans route "layers" onto the pipe axis; pp==1 plans do not
     assert p.sharding_rules().mesh_axis("layers") == "pipe"
     assert ParallelPlan().sharding_rules().mesh_axis("layers") is None
+
+
+SHARDED_INIT_CODE = '''
+import numpy as np, jax, jax.numpy as jnp
+from repro.configs import get_config
+from repro.models.model import Model
+from repro.optim import AdamWConfig
+from repro.runtime.train_loop import (ParallelPlan, init_train_state,
+                                      train_state_shardings)
+from repro.launch.mesh import mesh_for_plan
+
+cfg = get_config("yi-6b").reduced(n_layers=4, d_model=128, n_heads=4,
+                                  n_kv_heads=2, d_ff=256, vocab_size=256,
+                                  head_dim=32)
+model = Model(cfg, jnp.float32)
+opt = AdamWConfig(lr=1e-3)
+plan = ParallelPlan(tp=2, pp=2, gas=2, precision="fp32")
+mesh = mesh_for_plan(plan)
+eager = init_train_state(model, jax.random.PRNGKey(0), opt, plan)
+sharded = init_train_state(model, jax.random.PRNGKey(0), opt, plan, mesh=mesh)
+want = train_state_shardings(model, mesh, plan)
+for a, b, sh in zip(jax.tree.leaves(eager), jax.tree.leaves(sharded),
+                    jax.tree.leaves(want)):
+    assert b.sharding.is_equivalent_to(sh, b.ndim), (b.sharding, sh)
+    # jit may fuse the init's scale into the sampler: equal to fp32 rounding
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                               atol=0)
+# the stacked layer weights really are split over all four devices
+qkv = jax.tree.leaves(sharded["params"]["layers"])
+assert max(len({str(s.index) for s in x.addressable_shards}) for x in qkv) == 4
+print("SHARDED_INIT_OK")
+'''
+
+
+def test_init_train_state_builds_sharded_under_mesh(multidev):
+    """init_train_state(mesh=) returns the eager init's values already in
+    the plan's shardings (no device holds the whole unsharded state)."""
+    out = multidev(SHARDED_INIT_CODE, n_devices=4)
+    assert "SHARDED_INIT_OK" in out

@@ -174,7 +174,7 @@ def test_scan_telemetry_plan_and_kernels_invariant(fam):
                           kernels=True)]
     recs = []
     for plan in plans:
-        t = tel.Telemetry(cfg, plan, GB, S)
+        t = tel.Telemetry(cfg, plan, GB, S, machine=cm.TPU_V5E)
         t.step(1, 0.5, {"loss": np.float32(2.0), "loss_scale": 1.0,
                         "grad_norm": np.float32(0.5)})
         recs.append((plan, t.records[-1]))
@@ -240,11 +240,24 @@ def test_step_fields():
     assert f["tokens_per_s"] == pytest.approx(64 / 0.5)
     assert f["flops_per_step"] == flops
     assert f["tflops_per_device"] == pytest.approx(flops / (0.5 * 2) / 1e12)
-    assert 0.0 <= f["mfu"] <= 1.0
-    assert f["machine"] == cm.FRONTIER.name
-    # machine object accepted too
-    assert tel.step_fields(cfg, 4, 16, 0.5, 2,
-                           machine=cm.TPU_V5E)["machine"] == cm.TPU_V5E.name
+    # the CPU has no peak on record: MFU is not measured, no number given
+    assert f["mfu"] is None and f["machine"] is None
+    # an explicit machine supplies the peak
+    g = tel.step_fields(cfg, 4, 16, 0.5, 2, machine=cm.TPU_V5E)
+    assert g["machine"] == cm.TPU_V5E.name
+    assert g["mfu"] == pytest.approx(
+        tel.mfu(flops, 0.5, 2, cm.TPU_V5E.peak_flops))
+    assert 0.0 <= g["mfu"] <= 1.0
+
+
+def test_device_peak_table():
+    # one table keyed by device_kind; an unknown kind is an error, not a
+    # default peak
+    assert cm.machine_for_device_kind("TPU v5 lite") is cm.TPU_V5E
+    assert cm.TPU_V5E.peak_flops == 197e12
+    with pytest.raises(ValueError, match="no peak rates on record"):
+        cm.machine_for_device_kind("TPU v99")
+    assert tel.device_machine() is None     # this suite runs on the CPU
 
 
 def test_drift_monitor_warns_once_on_rolling_crossing():
@@ -309,11 +322,18 @@ def test_telemetry_records_roundtrip(tmp_path):
     assert comp["kernels_interpret_mode"] == (comp["backend"] == "cpu")
     assert step["tokens"] == 64 and step["grad_norm"] == 0.5
     assert step["drift"]["window"] == 1
-    assert 0.0 <= step["mfu"] <= 1.0
+    # CPU run: no device peak, so MFU is recorded as not measured
+    assert comp["machine"] is None and comp["peak_flops"] is None
+    assert step["mfu"] is None
     # console line: prefix byte-identical to the pre-telemetry format
     line = t.console_line(step, with_mfu=False)
     assert line == "step     1 loss 2.0000 scale 1 256 tok/s"
-    assert " mfu " in t.console_line(step, with_mfu=True)
+    assert t.console_line(step, with_mfu=True).endswith(" mfu not measured")
+    # with a device peak the same record carries a utilization
+    tv = tel.Telemetry(cfg, plan, 4, 16, machine=cm.TPU_V5E)
+    rv = tv.step(1, 0.25, {"loss": np.float32(2.0), "loss_scale": 1.0})
+    assert 0.0 <= rv["mfu"] <= 1.0
+    assert " mfu " in tv.console_line(rv, with_mfu=True)
 
 
 def test_validate_record_rejects_bad_records():
