@@ -71,7 +71,7 @@ def hillclimb_table() -> str:
 def telemetry_table(path: str) -> str:
     """Render a per-step table from a telemetry JSONL stream
     (``core/telemetry.py`` schema: one ``compile`` record, then ``step``
-    records carrying tokens/s, MFU, and the costmodel drift block)."""
+    records carrying tokens/s and MFU)."""
     recs = [json.loads(l) for l in open(path) if l.strip()]
     head = next((r for r in recs if r.get("kind") == "compile"), None)
     lines = []
@@ -82,23 +82,19 @@ def telemetry_table(path: str) -> str:
             f"devices={head.get('devices')} backend={head.get('backend')}")
         lines.append("")
     lines += [
-        "| step | wall | tokens/s | TFLOP/s/dev | MFU | loss | drift |",
-        "|---:|---:|---:|---:|---:|---:|---:|",
+        "| step | wall | tokens/s | TFLOP/s/dev | MFU | loss |",
+        "|---:|---:|---:|---:|---:|---:|",
     ]
     for r in recs:
         if r.get("kind") != "step":
             continue
-        d = r.get("drift") or {}
-        ratio = d.get("rolling_ratio", d.get("step_time_ratio"))
-        drift = "—" if ratio is None else (
-            f"{ratio:.2f}x" + (" ⚠" if d.get("warn") else ""))
         loss = r.get("loss")
         mfu = "not measured" if r["mfu"] is None else f"{r['mfu'] * 100:.2f}%"
         lines.append(
             f"| {r['step']} | {_fmt_s(r['wall_s'])} | "
             f"{r['tokens_per_s']:,.0f} | {r['tflops_per_device']:.3f} | "
             f"{mfu} | "
-            f"{'—' if loss is None else f'{loss:.4f}'} | {drift} |")
+            f"{'—' if loss is None else f'{loss:.4f}'} |")
     return "\n".join(lines)
 
 
@@ -116,7 +112,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--inject", action="store_true")
     ap.add_argument("--telemetry", metavar="JSONL", default=None,
-                    help="render a step/MFU/drift table from a telemetry "
+                    help="render a step/MFU table from a telemetry "
                          "JSONL (launch/train.py --log-jsonl output)")
     args = ap.parse_args()
     if args.telemetry:

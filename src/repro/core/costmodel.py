@@ -136,7 +136,7 @@ TPU_V5E = V5eMachine(
 )
 
 # The one table of chip peaks, keyed by ``jax.devices()[0].device_kind``:
-# MFU denominators and on-chip drift anchors come from here.
+# MFU denominators come from here.
 DEVICE_MACHINES: dict[str, Machine] = {
     "TPU v5 lite": TPU_V5E,
 }
@@ -218,8 +218,8 @@ class Prediction:
     moe_drop: float = 0.0
     # predicted per-device collective payload bytes per step, split by
     # mesh axis ({tp, ep, pp, dp, zero3_gather, total}) — the analytic
-    # anchor the telemetry drift monitor compares against the measured
-    # ``analysis/hlo.py:comm_bytes`` of the compiled module
+    # counterpart of the measured ``analysis/hlo.py:comm_bytes`` of the
+    # compiled module
     comm_bytes: dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
@@ -258,9 +258,9 @@ def predict(model: GPTSize, cfg: ParallelCfg, machine: Machine = FRONTIER) -> Pr
         t_attn_mem = 40.0 * score_bytes * layers_per_stage / machine.hbm_bw
         t_comp = t_comp / 0.88
 
-    # per-device wire payloads per step, split by mesh axis — the analytic
-    # side of the telemetry drift monitor (validated against the measured
-    # analysis/hlo.py:comm_bytes of the compiled module)
+    # per-device wire payloads per step, split by mesh axis (validated
+    # against the measured analysis/hlo.py:comm_bytes of the compiled
+    # module)
     cbytes = {"tp": 0.0, "ep": 0.0, "pp": 0.0, "dp": 0.0, "zero3_gather": 0.0}
     ticks_sched = m + p - 1
 
@@ -654,12 +654,10 @@ def predict_step(cfg, plan, global_batch: int, seq_len: int,
                  machine: Machine = FRONTIER) -> Prediction:
     """Costmodel prediction for an actual (ModelConfig, ParallelPlan) run.
 
-    The drift-monitor anchor: builds the analytic :class:`GPTSize` /
-    :class:`ParallelCfg` pair from the real model config and executor plan
-    and prices it with :func:`predict`.  For non-GPT families the size
-    mapping is structural (layers/width/heads) — the measured-over-
-    predicted ratio the telemetry records carry *is* the calibration
-    signal ``calibrate_bandwidths`` and the auto-planner consume.
+    The telemetry records' ``predicted`` block: builds the analytic
+    :class:`GPTSize` / :class:`ParallelCfg` pair from the real model config
+    and executor plan and prices it with :func:`predict`.  For non-GPT
+    families the size mapping is structural (layers/width/heads).
     """
     size = GPTSize(name=cfg.name, n_layers=cfg.n_layers, d_model=cfg.d_model,
                    n_heads=cfg.n_heads, vocab=cfg.padded_vocab, seq=seq_len)

@@ -1,4 +1,4 @@
-"""Telemetry: per-step structured records, MFU accounting, drift monitor.
+"""Telemetry: per-step structured records, MFU accounting, host spans.
 
 The paper's headline results are *measurements* — 38.38%/36.14%/31.96% GPU
 throughput (MFU) for 22B/175B/1T, bubble fractions, comm latency, memory
@@ -13,28 +13,36 @@ schema-tagged JSONL records (``SCHEMA``) carrying
   * training signals — loss / moe_aux / moe_drop / grad_norm / loss_scale,
   * one compile-time record with the per-class memory watermarks from
     ``runtime/train_loop.py:train_state_bytes``, XLA's peak-bytes estimate,
-    and the measured collective payload bytes from
-    ``analysis/hlo.py:comm_bytes`` on the compiled module,
-  * a **drift** block — the costmodel's predicted step time / comm bytes /
-    memory (``costmodel.predict_step``) next to the measured values, with a
-    measured/predicted ratio and a rolling-window summary
-    (:class:`DriftMonitor`); a threshold crossing emits a Python warning.
+    the measured collective payload bytes from ``analysis/hlo.py:comm_bytes``
+    on the compiled module, and the costmodel's ``predicted`` block.
+
+Beside the records, :func:`span` times named, nested host intervals (the
+serving engine's tick and its parts, ``runtime/serve_engine.py``).  Each
+span is a ``jax.profiler.TraceAnnotation`` called ``repro.<name>`` on the
+profiler's host plane, on the device trace's timeline, and on exit a
+:class:`SpanRecord` in the process-wide bounded log :data:`SPANS`, stamped
+with ``time.perf_counter``.  The log is always on; ``launch/serve.py
+--log-jsonl`` writes a run's spans as ``span`` records.
 
 Every record is passed through :func:`sanitize_record` (the shared helper
 dryrun/hillclimb also use): tracebacks stripped, numpy/jax scalars coerced
 to plain JSON types.  ``launch/train.py --log-jsonl`` writes the stream,
 ``launch/dryrun.py`` emits the same schema for lowered-only runs,
 ``benchmarks/*`` reuse the record fields (:func:`step_fields`), and
-``analysis/report.py`` renders the drift table.  The pipeline-timeline view
-of the same run lives in ``analysis/trace.py``.
+``analysis/report.py --telemetry`` renders the step table.  The
+pipeline-timeline view of the same run lives in ``analysis/trace.py``.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import itertools
 import json
+import threading
 import time
-import warnings
-from typing import Any, IO, Mapping
+from typing import Any, IO, Iterable, Mapping
+
+from jax.profiler import TraceAnnotation
 
 from repro.core import costmodel as cm
 
@@ -47,7 +55,7 @@ NOT_MEASURED = "not measured"
 _STEP_KEYS = frozenset({
     "schema", "kind", "step", "wall_s", "tokens", "tokens_per_s",
     "flops_per_step", "tflops_per_device", "mfu", "loss", "loss_scale",
-    "predicted", "drift",
+    "predicted",
 })
 _COMPILE_KEYS = frozenset({
     "schema", "kind", "arch", "family", "plan", "global_batch", "seq_len",
@@ -64,6 +72,10 @@ _REQUEST_KEYS = frozenset({
     "schema", "kind", "rid", "arch", "t_arrival", "t_admit",
     "t_first_token", "t_done", "n_prompt", "n_generated", "finish_reason",
     "evictions",
+})
+# host spans (``span``), written by launch/serve.py --log-jsonl
+_SPAN_KEYS = frozenset({
+    "schema", "kind", "id", "parent", "name", "start", "end", "attrs",
 })
 
 
@@ -134,42 +146,6 @@ def step_fields(cfg, global_batch: int, seq_len: int, wall_s: float,
     }
 
 
-@dataclasses.dataclass
-class DriftMonitor:
-    """Rolling measured/predicted ratio with a threshold warning.
-
-    A ratio of 1.0 means the costmodel's frozen calibration predicts this
-    machine perfectly; on this CPU container ratios are large and *that is
-    the point* — each record is a calibration sample for
-    ``costmodel.calibrate_bandwidths`` and the future auto-planner.
-    The warning only fires when the *rolling* ratio (median-free mean over
-    ``window`` steps) crosses ``threshold`` or 1/``threshold``, i.e. on
-    sustained drift, not a single straggler step.
-    """
-    threshold: float = 10.0
-    window: int = 20
-    _ratios: list[float] = dataclasses.field(default_factory=list)
-    _warned: bool = dataclasses.field(default=False)
-
-    def update(self, measured_s: float, predicted_s: float) -> dict:
-        ratio = measured_s / predicted_s if predicted_s > 0 else float("inf")
-        self._ratios.append(ratio)
-        tail = self._ratios[-self.window:]
-        rolling = sum(tail) / len(tail)
-        warn = rolling > self.threshold or rolling < 1.0 / self.threshold
-        if warn and not self._warned:
-            self._warned = True
-            warnings.warn(
-                f"costmodel drift: rolling measured/predicted step-time "
-                f"ratio {rolling:.2f} outside [1/{self.threshold:g}, "
-                f"{self.threshold:g}] over the last {len(tail)} steps — "
-                f"recalibrate with costmodel.calibrate_bandwidths",
-                stacklevel=3)
-        return {"step_time_ratio": ratio, "rolling_ratio": rolling,
-                "window": len(tail), "warn": warn,
-                "threshold": self.threshold}
-
-
 class JsonlSink:
     """Append-only JSONL writer; every record goes through
     :func:`sanitize_record` and is flushed immediately (crash-safe tail)."""
@@ -202,18 +178,17 @@ class Telemetry:
     ``cfg`` is the ``ModelConfig`` actually trained, ``plan`` the
     ``ParallelPlan`` (or duck-typed equivalent).  ``machine`` (default: the
     device's own, :func:`device_machine`) supplies the MFU peak and the
-    drift anchor; without one — on the CPU — ``mfu`` is recorded as
-    ``None`` ("not measured") and the anchor is the costmodel's calibrated
-    Frontier machine.  The analytic FLOPs and
-    the costmodel prediction are computed once here; each
+    machine the costmodel prices the plan on; without one — on the CPU —
+    ``mfu`` is recorded as ``None`` ("not measured") and the prediction is
+    for the costmodel's calibrated Frontier machine.  The analytic FLOPs
+    and the costmodel prediction are computed once here; each
     :meth:`step` call only does O(1) bookkeeping on top of the metrics the
     executor already returns.
     """
 
     def __init__(self, cfg, plan, global_batch: int, seq_len: int, *,
                  machine: cm.Machine | None = None,
-                 jsonl: str | None = None,
-                 drift_threshold: float = 10.0, drift_window: int = 20):
+                 jsonl: str | None = None):
         self.cfg, self.plan = cfg, plan
         self.global_batch, self.seq_len = global_batch, seq_len
         self.machine = machine or device_machine()
@@ -224,8 +199,6 @@ class Telemetry:
                                               self.machine or cm.FRONTIER)
         except Exception:                   # exotic plan the model can't price
             self.prediction = None
-        self.drift = DriftMonitor(threshold=drift_threshold,
-                                  window=drift_window)
         self.sink = JsonlSink(jsonl) if jsonl else None
         self.step_walls: list[float] = []
         self.records: list[dict] = []
@@ -303,9 +276,6 @@ class Telemetry:
                   "grads_finite"):
             if k in metrics:
                 rec[k] = metrics[k]
-        predicted_s = (self.prediction.step_time_s
-                       if self.prediction is not None else 0.0)
-        rec["drift"] = self.drift.update(wall_s, predicted_s)
         return self._emit(rec)
 
     def mfu(self, flops: float, wall_s: float) -> float | None:
@@ -348,8 +318,8 @@ class Telemetry:
 
 def predicted_block(prediction: cm.Prediction | None) -> dict:
     """Costmodel prediction as the record's ``predicted`` sub-dict — the
-    fields the drift monitor and ``analysis/report.py`` compare against
-    measurements (dryrun emits the same block on lowered-only runs)."""
+    analytic side of a record (dryrun emits the same block on lowered-only
+    runs)."""
     if prediction is None:
         return {}
     return {
@@ -384,6 +354,112 @@ def timed_call(fn, *args):
 
 
 # ---------------------------------------------------------------------------
+# Host spans: named, nested intervals on the profiler's timeline and in a
+# bounded in-process log
+# ---------------------------------------------------------------------------
+
+SPAN_PREFIX = "repro."
+SPAN_LOG_LEN = 65536
+
+
+@dataclasses.dataclass(slots=True)
+class SpanRecord:
+    """One finished span: ``start``/``end`` on ``time.perf_counter``,
+    ``parent`` the id of the span that enclosed it (``None`` at the top),
+    ``attrs`` its attributes, counters set inside the span included."""
+    id: int
+    parent: int | None
+    name: str
+    start: float
+    end: float
+    attrs: dict
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+class SpanLog:
+    """The process-wide log of finished spans, newest last, holding at most
+    ``maxlen`` (the oldest fall out)."""
+
+    def __init__(self, maxlen: int = SPAN_LOG_LEN):
+        self.records: collections.deque[SpanRecord] = \
+            collections.deque(maxlen=maxlen)
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+
+    def _stack(self) -> list[int]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def between(self, t0: float, t1: float,
+                name: str | None = None) -> list[SpanRecord]:
+        """The spans that lie wholly inside ``[t0, t1]``, oldest first;
+        only those called ``name`` where it is given."""
+        return [s for s in list(self.records)
+                if t0 <= s.start and s.end <= t1
+                and (name is None or s.name == name)]
+
+
+SPANS = SpanLog()
+
+
+def self_seconds(span: SpanRecord, spans: Iterable[SpanRecord],
+                 child: str | None = None) -> float:
+    """``span``'s duration less what its direct children among ``spans``
+    cover (only those called ``child``, where it is given)."""
+    return span.seconds - sum(
+        s.seconds for s in spans
+        if s.parent == span.id and (child is None or s.name == child))
+
+
+class span:
+    """``with span(name, **attrs) as attrs:`` times the enclosed block.
+
+    The block may add counters to the yielded ``attrs`` dict.  While the
+    profiler records, the span is also a ``TraceAnnotation`` called
+    ``repro.<name>`` carrying the attributes on exit; with it off a span
+    costs two clock reads and one append to :data:`SPANS`."""
+
+    __slots__ = ("name", "attrs", "_id", "_parent", "_start", "_ann")
+
+    def __init__(self, name: str, **attrs: Any):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self) -> dict:
+        stack = SPANS._stack()
+        self._parent = stack[-1] if stack else None
+        self._id = next(SPANS._ids)
+        stack.append(self._id)
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(SPAN_PREFIX + self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._start = time.perf_counter()
+        return self.attrs
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.set_metadata(**self.attrs)
+            self._ann.__exit__(*exc)
+        SPANS._stack().pop()
+        SPANS.records.append(SpanRecord(self._id, self._parent, self.name,
+                                        self._start, end, self.attrs))
+
+
+def span_record(s: SpanRecord) -> dict:
+    """A span as a ``span`` record of the telemetry schema."""
+    return sanitize_record({
+        "schema": SCHEMA, "kind": "span", "id": s.id, "parent": s.parent,
+        "name": s.name, "start": s.start, "end": s.end, "attrs": s.attrs})
+
+
+# ---------------------------------------------------------------------------
 # Schema validation (tests + the CI telemetry job run this on real files)
 # ---------------------------------------------------------------------------
 
@@ -403,6 +479,8 @@ def validate_record(rec: Mapping[str, Any]) -> None:
             raise ValueError("ok train dryrun record missing 'predicted'")
     elif kind == "request":
         missing = _REQUEST_KEYS - rec.keys()
+    elif kind == "span":
+        missing = _SPAN_KEYS - rec.keys()
     else:
         raise ValueError(f"unknown record kind {kind!r}")
     if missing:
@@ -416,11 +494,9 @@ def validate_record(rec: Mapping[str, Any]) -> None:
                 a <= b + 1e-9 for a, b in zip(t, t[1:])):
             raise ValueError(
                 f"request timestamps not monotone: {t}")
+    if kind == "span" and not rec["start"] <= rec["end"]:
+        raise ValueError(f"span {rec['name']!r} ends before it starts")
     if kind == "step":
-        d = rec["drift"]
-        for k in ("step_time_ratio", "rolling_ratio", "warn", "threshold"):
-            if k not in d:
-                raise ValueError(f"drift block missing {k!r}")
         if rec["mfu"] is not None and not (0.0 <= rec["mfu"] <= 1.0):
             raise ValueError(f"mfu {rec['mfu']} outside [0, 1]")
     if kind == "compile":

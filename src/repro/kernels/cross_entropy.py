@@ -111,6 +111,7 @@ def ce_logsumexp_pallas(h: jax.Array, w: jax.Array, labels: jax.Array, *,
             pltpu.VMEM((block_n, 1), jnp.float32),
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
+        name="cross_entropy",
         interpret=interpret,
     )(h, w, labels.reshape(rows, 1))
     return lse[:N, 0], ylogit[:N, 0]

@@ -154,6 +154,7 @@ def flash_attention_fwd(q, k, v, *, causal, window, q_offset,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse
@@ -316,6 +317,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, q_offset,
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -355,6 +357,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, q_offset,
             pltpu.VMEM((block_k, hd), jnp.float32),
             pltpu.VMEM((block_k, hd), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

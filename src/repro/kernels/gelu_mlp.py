@@ -52,6 +52,7 @@ def gelu_mlp_fwd_pallas(x2d: jax.Array, w1: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bn, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, F), x2d.dtype),
+        name="gelu_mlp_in",
         interpret=interpret,
     )(x2d, w1)
 

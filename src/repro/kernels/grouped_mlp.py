@@ -101,6 +101,7 @@ def _fwd_pallas(x, w1, w3, w2, mask, *, block_n: int, interpret: bool):
         # accumulator need ~40 MiB: above the 16 MiB default scoped limit,
         # well inside a v5e core's 128 MiB of VMEM
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        name="grouped_mlp",
         interpret=interpret,
     )(*args)
 

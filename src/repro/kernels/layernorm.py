@@ -45,6 +45,7 @@ def layernorm_fwd_pallas(x2d: jax.Array, w: jax.Array, b: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
+        name="layernorm",
         interpret=interpret,
     )(x2d, w, b)
 

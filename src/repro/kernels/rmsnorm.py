@@ -40,6 +40,7 @@ def rmsnorm_fwd_pallas(x2d: jax.Array, w: jax.Array, *, eps: float,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(x2d, w)
 

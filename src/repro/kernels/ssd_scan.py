@@ -122,6 +122,7 @@ def _fwd_pallas(x, dt, Bm, Cm, A_log, *, chunk: int, interpret: bool):
             # VMEM recurrent state carried across the nc chunk loop
             pltpu.VMEM((P, N), jnp.float32),
         ],
+        name="ssd_scan",
         interpret=interpret,
     )(x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1)[..., None], Bm, Cm, logA)
     return y.transpose(0, 2, 1, 3), state
@@ -208,6 +209,7 @@ def mamba_decode_step(window, conv_w, conv_b, dt_raw, dt_bias, A_log, D,
             jax.ShapeDtypeStruct((B, H, P, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
+        name="ssd_decode",
         interpret=interpret,
     )(xin.astype(jnp.float32).reshape(B, H, P, 1), gate(dt), gate(a),
       row(Bm), row(Cm), D.astype(jnp.float32).reshape(H, 1, 1), state)

@@ -48,6 +48,7 @@ def swiglu_fwd_pallas(x2d: jax.Array, w1: jax.Array, w3: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bn, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, F), x2d.dtype),
+        name="swiglu_in",
         interpret=interpret,
     )(x2d, w1, w3)
 

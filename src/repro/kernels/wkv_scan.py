@@ -135,6 +135,7 @@ def _fwd_pallas(r, k, v, w, u, state, *, chunk: int, interpret: bool):
             # VMEM recurrent state carried across the nc chunk loop
             pltpu.VMEM((K, V), jnp.float32),
         ],
+        name="wkv_scan",
         interpret=interpret,
     )(hm(r), hm(k), hm(v), hm(cum), hm(cum_prev), u.reshape(H, 1, K), state)
     return hm(y), st
@@ -213,6 +214,7 @@ def wkv_decode_step(r, k, v, w, u, state, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H, 1, V), jnp.float32),
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
+        name="wkv_decode",
         interpret=interpret,
     )(r[..., None], k[..., None], v[:, :, None, :], w[..., None],
       u[..., None], state)

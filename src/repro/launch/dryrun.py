@@ -129,9 +129,9 @@ def lower_step(arch: str, shape_name: str, *, multi_pod: bool,
         # peak in the record
         meta["state_bytes"] = train_state_bytes(model, mesh, plan)
         # the telemetry record schema's analytic side (core/telemetry.py):
-        # family-aware model FLOPs + the costmodel prediction this shape
-        # would drift against if it ran — lowered-only runs emit the same
-        # blocks a live train's records carry
+        # family-aware model FLOPs + the costmodel prediction for this
+        # shape — lowered-only runs emit the same blocks a live train's
+        # records carry
         meta["flops_per_step"] = cm.train_step_flops(
             cfg, shape.global_batch, shape.seq_len).total
         try:

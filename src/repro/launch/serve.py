@@ -3,7 +3,9 @@ Poisson traffic, reporting per-request latency / TTFT percentiles and
 goodput.  ``--static`` runs the static-batch baseline (admission only when
 every decode slot has drained) for an apples-to-apples comparison;
 ``--log-jsonl`` streams one ``repro.telemetry/1`` ``request`` record per
-completed request.
+completed request, then writes the run's engine spans (``serve.tick`` and
+its parts, ``runtime/serve_engine.py``) as ``span`` records: where a tick's
+host time went, and its counters (live slots, admissions, free blocks).
 
   PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --reduced \
       --requests 16 --rate 4 --n-slots 4 --log-jsonl serve_requests.jsonl
@@ -18,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ASSIGNED, PAPER, get_config
-from repro.core.telemetry import JsonlSink
+from repro.core.telemetry import SPANS, JsonlSink, span_record
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.runtime.serve_engine import Request, ServeEngine
@@ -129,8 +131,10 @@ def main() -> None:
     print(f"{cfg.name} [{cfg.family}] {mode} batching, "
           f"{args.n_slots} slots, {pool}")
     t0 = time.monotonic()
+    t_spans = time.perf_counter()
     engine.run(reqs)
     wall = time.monotonic() - t0
+    spans = SPANS.between(t_spans, time.perf_counter())
     s = summarize(engine.records)
     print(f"{s['n_requests']} requests, {s['completed_tokens']} tokens in "
           f"{wall:.2f}s wall ({engine.n_ticks} decode ticks, "
@@ -140,8 +144,10 @@ def main() -> None:
           f"{s['latency_p99_s'] * 1e3:.0f} ms | ttft p50 "
           f"{s['ttft_p50_s'] * 1e3:.0f} ms p99 {s['ttft_p99_s'] * 1e3:.0f} ms")
     if sink is not None:
+        for sp in spans:
+            sink.write(span_record(sp))
         sink.close()
-        print(f"request records -> {args.log_jsonl}")
+        print(f"request records and {len(spans)} spans -> {args.log_jsonl}")
 
 
 if __name__ == "__main__":

@@ -139,14 +139,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-jsonl", default=None, metavar="PATH",
                     help="append one telemetry record per step "
-                         "(core/telemetry.py schema: tokens/s, MFU, drift)")
+                         "(core/telemetry.py schema: tokens/s, MFU)")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a Chrome-trace/Perfetto JSON of the "
                          "pipeline schedule against measured step times "
                          "(analysis/trace.py; view at chrome://tracing)")
-    ap.add_argument("--drift-threshold", type=float, default=10.0,
-                    help="warn when the rolling measured/predicted "
-                         "step-time ratio leaves [1/x, x]")
     return ap
 
 
@@ -182,8 +179,7 @@ def train(cfg: ModelConfig, plan: ParallelPlan, *, steps: int,
           global_batch: int, seq_len: int, lr: float = 1e-3, seed: int = 0,
           ckpt_dir: str | None = None, ckpt_every: int = 50,
           log_every: int = 10, log_jsonl: str | None = None,
-          trace: str | None = None,
-          drift_threshold: float = 10.0) -> TrainRun:
+          trace: str | None = None) -> TrainRun:
     """Train ``cfg`` under ``plan`` on this process's devices for ``steps``
     optimizer steps of synthetic data from ``seed``."""
     # --kernels is fully fused on every family now: rmsnorm + layernorm,
@@ -227,12 +223,8 @@ def train(cfg: ModelConfig, plan: ParallelPlan, *, steps: int,
     # the MFU console suffix appears only when telemetry output was asked
     # for, keeping the documented default step-line format byte-identical
     tele_on = bool(log_jsonl or trace)
-    tele = telemetry.Telemetry(
-        cfg, plan, global_batch, seq_len, jsonl=log_jsonl,
-        # the drift warning only fires on runs that asked for telemetry
-        # output — a smoke run on this CPU container always drifts hugely
-        # and the default console should stay as quiet as before
-        drift_threshold=drift_threshold if tele_on else float("inf"))
+    tele = telemetry.Telemetry(cfg, plan, global_batch, seq_len,
+                               jsonl=log_jsonl)
 
     # AOT compile: one .lower().compile() captures the measured collective
     # payload bytes + XLA's peak estimate for the compile record, and the
@@ -280,7 +272,7 @@ def main(argv: list[str] | None = None) -> None:
                 seq_len=args.seq_len, lr=args.lr, seed=args.seed,
                 ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
                 log_every=args.log_every, log_jsonl=args.log_jsonl,
-                trace=args.trace, drift_threshold=args.drift_threshold)
+                trace=args.trace)
     walls = run.step_walls
     if walls:
         med = sorted(walls)[len(walls) // 2]

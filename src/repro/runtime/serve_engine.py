@@ -31,6 +31,21 @@ the *request* layer on top of it —
 
 Every finished request emits a ``repro.telemetry/1`` ``request`` record
 (arrival/admit/first-token/done timestamps, token counts, finish reason).
+Each tick and its parts are host spans (``core/telemetry.py:span``), with
+the tick's counters as attributes:
+
+  serve.tick          the whole of :meth:`ServeEngine.step`: ``tick``,
+                      ``n_slots``, ``live`` (slots decoded), ``admitted``,
+                      ``kv_positions`` (positions the decoded slots attend),
+                      ``free_blocks`` (after growth), ``evicted``
+  serve.admit         one admission: ``rid``, ``prompt_len`` (replayed
+                      prefix included), ``bucket``, ``replay``
+  serve.prefill       its prefill and the scatter into the cache (``rid``)
+  serve.grow_blocks   block growth: ``blocks`` taken, ``evictions``
+  serve.decode        the decode step and the sampler's dispatch
+  serve.wait          a host pull that waits on the device: ``for`` is
+                      ``prefill`` (the first token) or ``decode``
+  serve.bookkeep      token accounting, finishes and releases: ``finished``
 """
 from __future__ import annotations
 
@@ -300,56 +315,64 @@ class ServeEngine:
         L = len(prompt)
         total = L + self.patch_off
         bucket = L if self.exact_prefill else self._bucket(L)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[:, :L] = prompt
-        batch: dict[str, Any] = {"tokens": jnp.asarray(toks)}
-        if req.extras:
-            for k, v in req.extras.items():
-                batch[k] = jnp.asarray(v)[None]
-        logits, small = self._get_prefill(bucket)(
-            self.params, batch, jnp.asarray([L], np.int32))
-        self.n_prefills += 1
+        with tel.span("serve.admit", rid=req.rid, prompt_len=L,
+                      bucket=bucket, replay=len(gen)):
+            with tel.span("serve.prefill", rid=req.rid):
+                toks = np.zeros((1, bucket), np.int32)
+                toks[:, :L] = prompt
+                batch: dict[str, Any] = {"tokens": jnp.asarray(toks)}
+                if req.extras:
+                    for k, v in req.extras.items():
+                        batch[k] = jnp.asarray(v)[None]
+                logits, small = self._get_prefill(bucket)(
+                    self.params, batch, jnp.asarray([L], np.int32))
+                self.n_prefills += 1
 
-        slot = self.slots[slot_idx]
-        if self.paged:
-            n_keep = total // self.block_size + 1
-            blocks = [self.free_blocks.pop() for _ in range(n_keep)]
-            nb_bucket = _round_up(bucket + self.patch_off,
-                                  self.block_size) // self.block_size
-            nb_real = min(n_keep, nb_bucket)
-            targets = np.zeros(nb_bucket, np.int32)  # pad blocks -> garbage
-            targets[:nb_real] = blocks[:nb_real]
-            self.bt[slot_idx] = 0
-            self.bt[slot_idx, :n_keep] = blocks
-            self.cache = self._admit_fn(self.cache, small["layers"],
-                                        jnp.asarray(targets),
-                                        slot_idx, total)
-            slot.blocks = blocks
-        else:
-            self.cache = self._admit_fn(self.cache, small, slot_idx, total)
-        if self._encode is not None:
-            mem = self._encode(self.params, jnp.asarray(req.extras["frames"])[None])
-            self.memory = self.memory.at[slot_idx].set(mem[0])
+                slot = self.slots[slot_idx]
+                if self.paged:
+                    n_keep = total // self.block_size + 1
+                    blocks = [self.free_blocks.pop() for _ in range(n_keep)]
+                    nb_bucket = _round_up(bucket + self.patch_off,
+                                          self.block_size) // self.block_size
+                    nb_real = min(n_keep, nb_bucket)
+                    # pad blocks -> garbage
+                    targets = np.zeros(nb_bucket, np.int32)
+                    targets[:nb_real] = blocks[:nb_real]
+                    self.bt[slot_idx] = 0
+                    self.bt[slot_idx, :n_keep] = blocks
+                    self.cache = self._admit_fn(self.cache, small["layers"],
+                                                jnp.asarray(targets),
+                                                slot_idx, total)
+                    slot.blocks = blocks
+                else:
+                    self.cache = self._admit_fn(self.cache, small, slot_idx,
+                                                total)
+                if self._encode is not None:
+                    mem = self._encode(
+                        self.params, jnp.asarray(req.extras["frames"])[None])
+                    self.memory = self.memory.at[slot_idx].set(mem[0])
 
-        slot.req = req
-        slot.pos = total
-        slot.admit_seq = self._admit_seq
-        self._admit_seq += 1
-        self.temps[slot_idx] = req.temperature
-        self.top_ps[slot_idx] = req.top_p
-        self.seeds[slot_idx] = req.seed
-        self.steps[slot_idx] = len(gen)
-        now = self._now()
-        if st["t_admit"] is None:
-            st["t_admit"] = now
+            slot.req = req
+            slot.pos = total
+            slot.admit_seq = self._admit_seq
+            self._admit_seq += 1
+            self.temps[slot_idx] = req.temperature
+            self.top_ps[slot_idx] = req.top_p
+            self.seeds[slot_idx] = req.seed
+            self.steps[slot_idx] = len(gen)
+            now = self._now()
+            if st["t_admit"] is None:
+                st["t_admit"] = now
 
-        # first token of this admission comes straight from prefill logits
-        tok = int(np.asarray(sample_tokens(
-            logits, jnp.asarray(self.temps[slot_idx:slot_idx + 1]),
-            jnp.asarray(self.top_ps[slot_idx:slot_idx + 1]),
-            jnp.asarray(self.seeds[slot_idx:slot_idx + 1]),
-            jnp.asarray(self.steps[slot_idx:slot_idx + 1])))[0])
-        self._take_token(slot_idx, tok)
+            # first token of this admission comes straight from prefill logits
+            first = sample_tokens(
+                logits, jnp.asarray(self.temps[slot_idx:slot_idx + 1]),
+                jnp.asarray(self.top_ps[slot_idx:slot_idx + 1]),
+                jnp.asarray(self.seeds[slot_idx:slot_idx + 1]),
+                jnp.asarray(self.steps[slot_idx:slot_idx + 1]))
+            with tel.span("serve.wait", rid=req.rid, **{"for": "prefill"}):
+                tok = int(np.asarray(first)[0])
+            self._take_token(slot_idx, tok)
 
     def _take_token(self, slot_idx: int, tok: int) -> None:
         """Account one sampled token for the slot's request; finish or
@@ -431,52 +454,69 @@ class ServeEngine:
     def _grow_blocks(self) -> None:
         """Allocate the next physical block for any paged slot whose next
         write position crosses its allocation; evict under pressure."""
-        for i, slot in enumerate(self.slots):
-            while (slot.req is not None
-                   and slot.pos // self.block_size >= len(slot.blocks)):
-                if not self.free_blocks:
-                    if not self._evict_one(exclude=i):
-                        raise RuntimeError(
-                            "paged pool exhausted with nothing evictable")
-                    continue
-                blk = self.free_blocks.pop()
-                self.bt[i, len(slot.blocks)] = blk
-                slot.blocks.append(blk)
+        with tel.span("serve.grow_blocks") as attrs:
+            taken, evicted = 0, self.n_evictions
+            for i, slot in enumerate(self.slots):
+                while (slot.req is not None
+                       and slot.pos // self.block_size >= len(slot.blocks)):
+                    if not self.free_blocks:
+                        if not self._evict_one(exclude=i):
+                            raise RuntimeError(
+                                "paged pool exhausted with nothing evictable")
+                        continue
+                    blk = self.free_blocks.pop()
+                    self.bt[i, len(slot.blocks)] = blk
+                    slot.blocks.append(blk)
+                    taken += 1
+            attrs.update(blocks=taken, evictions=self.n_evictions - evicted)
 
     def step(self) -> list[int]:
         """One engine tick: admissions, paged-block growth, one decode
         step over the slot batch, sampling, stop handling.  Returns the
         rids that finished this tick."""
-        self._admit_ready()
-        active = [i for i, s in enumerate(self.slots) if s.req is not None]
-        if not active:
-            return []
-        if self.paged:
-            self._grow_blocks()
+        with tel.span("serve.tick", tick=self.n_ticks,
+                      n_slots=self.n_slots) as attrs:
+            prefills, evictions = self.n_prefills, self.n_evictions
+            self._admit_ready()
+            if self.paged and any(s.req is not None for s in self.slots):
+                self._grow_blocks()
             active = [i for i, s in enumerate(self.slots) if s.req is not None]
-        mask = np.zeros(self.n_slots, bool)
-        mask[active] = True
-        tokens = np.array([s.next_token for s in self.slots],
-                          np.int32)[:, None]
-        batch: dict[str, Any] = {"token": jnp.asarray(tokens),
-                                 "active": jnp.asarray(mask)}
-        if self.paged:
-            batch["block_table"] = jnp.asarray(self.bt)
-        if self._encode is not None:
-            batch["memory"] = self.memory
-        logits, self.cache = self._decode(self.params, self.cache, batch)
-        sampled = np.asarray(sample_tokens(
-            logits, jnp.asarray(self.temps), jnp.asarray(self.top_ps),
-            jnp.asarray(self.seeds), jnp.asarray(self.steps)))
-        self.n_ticks += 1
-        finished = []
-        for i in active:
-            self.slots[i].pos += 1
-            before = self.slots[i].req.rid
-            self._take_token(i, int(sampled[i]))
-            if self.slots[i].req is None:
-                finished.append(before)
-        return finished
+            attrs.update(
+                live=len(active), admitted=self.n_prefills - prefills,
+                kv_positions=sum(self.slots[i].pos + 1 for i in active),
+                free_blocks=len(self.free_blocks) if self.paged else None,
+                evicted=self.n_evictions - evictions)
+            if not active:
+                return []
+            with tel.span("serve.decode"):
+                mask = np.zeros(self.n_slots, bool)
+                mask[active] = True
+                tokens = np.array([s.next_token for s in self.slots],
+                                  np.int32)[:, None]
+                batch: dict[str, Any] = {"token": jnp.asarray(tokens),
+                                         "active": jnp.asarray(mask)}
+                if self.paged:
+                    batch["block_table"] = jnp.asarray(self.bt)
+                if self._encode is not None:
+                    batch["memory"] = self.memory
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  batch)
+                sampled = sample_tokens(
+                    logits, jnp.asarray(self.temps), jnp.asarray(self.top_ps),
+                    jnp.asarray(self.seeds), jnp.asarray(self.steps))
+            with tel.span("serve.wait", **{"for": "decode"}):
+                sampled = np.asarray(sampled)
+            self.n_ticks += 1
+            with tel.span("serve.bookkeep") as done:
+                finished = []
+                for i in active:
+                    self.slots[i].pos += 1
+                    before = self.slots[i].req.rid
+                    self._take_token(i, int(sampled[i]))
+                    if self.slots[i].req is None:
+                        finished.append(before)
+                done["finished"] = len(finished)
+            return finished
 
     # ------------------------------------------------------------------
     # Drive to completion

@@ -396,10 +396,6 @@ def build_train_step(model: Model, opt_cfg: AdamWConfig, plan: ParallelPlan,
         def split(x):
             return x.reshape(outer_gas, x.shape[0] // outer_gas, *x.shape[1:])
 
-        micro = jax.tree.map(split, batch)
-        zero_grads = constrain_gsum(jax.tree.map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params))
-
         def accum(carry, mb):
             gsum, ce_sum, aux_sum, drop_sum = carry
             (_, metrics), grads = jax.value_and_grad(
@@ -409,22 +405,29 @@ def build_train_step(model: Model, opt_cfg: AdamWConfig, plan: ParallelPlan,
             return (gsum, ce_sum + metrics["ce"], aux_sum + metrics["moe_aux"],
                     drop_sum + metrics["moe_drop"]), None
 
-        (gsum, ce_sum, aux_sum, drop_sum), _ = jax.lax.scan(
-            accum, (zero_grads, jnp.float32(0.0), jnp.float32(0.0),
-                    jnp.float32(0.0)), micro)
+        # the two halves carry names into the HLO's op metadata
+        with jax.named_scope("loss_and_grad"):
+            micro = jax.tree.map(split, batch)
+            zero_grads = constrain_gsum(jax.tree.map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params))
+            (gsum, ce_sum, aux_sum, drop_sum), _ = jax.lax.scan(
+                accum, (zero_grads, jnp.float32(0.0), jnp.float32(0.0),
+                        jnp.float32(0.0)), micro)
 
-        grads = prec.unscale_grads(state["loss_scale"],
-                                   jax.tree.map(lambda g: g / outer_gas, gsum))
-        finite = prec.all_finite(grads)
-        # global fp32 L2 norm of the unscaled gradient — the telemetry
-        # record's training-health signal (sum-of-squares over sharded
-        # leaves reduces correctly under GSPMD)
-        grad_norm = jnp.sqrt(sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in jax.tree.leaves(grads)))
-        new_params, new_opt = adamw_update(
-            opt_cfg, params, grads, state["opt"], skip=~finite)
-        new_ls = prec.update_loss_scale(state["loss_scale"], finite)
+        with jax.named_scope("optimizer"):
+            grads = prec.unscale_grads(
+                state["loss_scale"],
+                jax.tree.map(lambda g: g / outer_gas, gsum))
+            finite = prec.all_finite(grads)
+            # global fp32 L2 norm of the unscaled gradient — the telemetry
+            # record's training-health signal (sum-of-squares over sharded
+            # leaves reduces correctly under GSPMD)
+            grad_norm = jnp.sqrt(sum(
+                jnp.sum(jnp.square(g.astype(jnp.float32)))
+                for g in jax.tree.leaves(grads)))
+            new_params, new_opt = adamw_update(
+                opt_cfg, params, grads, state["opt"], skip=~finite)
+            new_ls = prec.update_loss_scale(state["loss_scale"], finite)
         metrics = {
             "loss": ce_sum / outer_gas,
             "moe_aux": aux_sum / outer_gas,

@@ -1,4 +1,4 @@
-"""Telemetry: analytic FLOPs counter, MFU accounting, drift monitor, schema.
+"""Telemetry: analytic FLOPs counter, MFU accounting, schema.
 
 Covers the telemetry PR's acceptance bar:
   * the per-family analytic FLOPs counter (costmodel.train_step_flops)
@@ -7,7 +7,7 @@ Covers the telemetry PR's acceptance bar:
     and quadratically in seq for attention; forward-only is total/3;
   * every assigned family prices to a positive total with the right
     attn/scan structure (rwkv scan-only, hybrid both, encdec encoder);
-  * MFU / step_fields / DriftMonitor / sanitize_record unit behaviour;
+  * MFU / step_fields / sanitize_record unit behaviour;
   * Telemetry end-to-end: compile + step records through a JSONL sink,
     schema-validated on re-read; the console line keeps the documented
     pre-telemetry prefix byte-identically;
@@ -17,8 +17,6 @@ Covers the telemetry PR's acceptance bar:
     the layout.
 """
 import json
-import math
-import warnings
 
 import numpy as np
 import pytest
@@ -224,7 +222,7 @@ def test_predict_step_returns_anchor():
 
 
 # ---------------------------------------------------------------------------
-# mfu / step_fields / DriftMonitor / sanitize_record
+# mfu / step_fields / sanitize_record
 # ---------------------------------------------------------------------------
 
 def test_mfu():
@@ -258,28 +256,6 @@ def test_device_peak_table():
     with pytest.raises(ValueError, match="no peak rates on record"):
         cm.machine_for_device_kind("TPU v99")
     assert tel.device_machine() is None     # this suite runs on the CPU
-
-
-def test_drift_monitor_warns_once_on_rolling_crossing():
-    mon = tel.DriftMonitor(threshold=10.0, window=4)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        d = mon.update(5.0, 1.0)          # ratio 5: inside the band
-    assert d["step_time_ratio"] == pytest.approx(5.0) and not d["warn"]
-    with pytest.warns(UserWarning, match="costmodel drift"):
-        d = mon.update(100.0, 1.0)        # rolling (5+100)/2 crosses 10
-    assert d["warn"] and d["rolling_ratio"] == pytest.approx(52.5)
-    with warnings.catch_warnings():       # one-shot: no second warning
-        warnings.simplefilter("error")
-        d = mon.update(100.0, 1.0)
-    assert d["warn"] and d["window"] == 3
-    assert math.isinf(tel.DriftMonitor().update(1.0, 0.0)["step_time_ratio"])
-
-
-def test_drift_monitor_warns_on_overprediction_too():
-    mon = tel.DriftMonitor(threshold=10.0, window=2)
-    with pytest.warns(UserWarning, match="costmodel drift"):
-        mon.update(0.001, 1.0)            # 1000x faster than predicted
 
 
 def test_sanitize_record():
@@ -321,7 +297,10 @@ def test_telemetry_records_roundtrip(tmp_path):
     assert comp["flops_per_step"] == t.flops.total
     assert comp["kernels_interpret_mode"] == (comp["backend"] == "cpu")
     assert step["tokens"] == 64 and step["grad_norm"] == 0.5
-    assert step["drift"]["window"] == 1
+    # the costmodel's prediction rides every step record, next to the
+    # measured wall; no measured-over-predicted block is derived from it
+    assert step["predicted"] == tel.predicted_block(t.prediction)
+    assert "drift" not in step
     # CPU run: no device peak, so MFU is recorded as not measured
     assert comp["machine"] is None and comp["peak_flops"] is None
     assert step["mfu"] is None

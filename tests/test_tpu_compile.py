@@ -124,8 +124,31 @@ CASES = {
 }
 
 
+# the stable ``name=`` each case's Pallas calls carry into the compiled
+# module's instruction names (and so into the device trace's op events)
+KERNEL_NAMES = {
+    "flash_hd88": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "flash_hd128_gqa": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "swiglu": ("swiglu_in",),
+    "gelu_mlp": ("gelu_mlp_in",),
+    "rmsnorm": ("rmsnorm",),
+    "layernorm": ("layernorm",),
+    "cross_entropy": ("cross_entropy",),
+    "grouped_mlp_gelu": ("grouped_mlp",),
+    "grouped_mlp_swiglu": ("grouped_mlp",),
+    "ssd_scan": ("ssd_scan",),
+    "wkv_scan": ("wkv_scan",),
+    "mamba_decode_step": ("ssd_decode",),
+    "wkv_decode_step": ("wkv_decode",),
+}
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes, with_grad = CASES[name]
     text = _compile(fn, shapes, one_chip, grad=with_grad)
     assert "tpu_custom_call" in text, name
+    calls = [line.split(" = ", 1)[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel in KERNEL_NAMES[name]:
+        assert any(kernel in c for c in calls), (name, kernel, calls)

@@ -149,7 +149,8 @@ def test_train_trace_end_to_end(multidev, tmp_path):
     steps = [r for r in recs if r["kind"] == "step"]
     assert len(steps) == 2
     # a CPU run records MFU as not measured (no device peak)
-    assert all("drift" in r and r["mfu"] is None for r in steps)
+    assert all("predicted" in r and "drift" not in r and r["mfu"] is None
+               for r in steps)
 
     # the acceptance bound: measured idle within 15% of the analytic
     # wave bubble for (p=2, m=4, v=2)
