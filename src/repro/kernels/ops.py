@@ -179,3 +179,18 @@ def grouped_mlp(x: jax.Array, w1: jax.Array, w3: jax.Array | None,
     if interpret is None:
         interpret = _on_cpu()
     return gm.grouped_mlp(x, w1, w3, w2, mask, act=act, interpret=interpret)
+
+
+def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                    block_table: jax.Array, lengths: jax.Array, *,
+                    softcap: float | None = None,
+                    interpret: bool | None = None) -> jax.Array:
+    """Paged decode attention over the pool's live blocks: q (B, Hq, hd),
+    k_pool/v_pool (n_blocks, bs, Hkv, hd), block_table (B, max_blocks),
+    lengths (B,) (``pos + 1``; 0 for an inactive slot, which yields zeros)
+    -> (B, Hq, hd).  Serving path only (no vjp)."""
+    from repro.kernels import paged_attention as pa
+    if interpret is None:
+        interpret = _on_cpu()
+    return pa.paged_attention(q, k_pool, v_pool, block_table, lengths,
+                              softcap=softcap, interpret=interpret)

@@ -149,6 +149,16 @@ def self_attn_decode(
     return x + out, new_cache
 
 
+def paged_kernel_route(*, quant: bool, unsharded: bool) -> bool:
+    """Whether paged decode attention takes the Pallas kernel
+    (``kernels/paged_attention.py``): on a TPU, over an unquantized pool,
+    in a decode built without a mesh.  Elsewhere it gathers the block
+    table: on the CPU the kernel would run interpreted on every tick, an
+    int8 pool is dequantized by the gather, and GSPMD does not partition a
+    Mosaic call."""
+    return unsharded and not quant and jax.default_backend() == "tpu"
+
+
 def paged_attn_decode(
     params: dict,
     x: jax.Array,              # (B, 1, d)
@@ -157,6 +167,7 @@ def paged_attn_decode(
     pos: jax.Array,            # (B,) int32 — absolute write position per slot
     cfg: ModelConfig,
     active: jax.Array | None = None,   # (B,) — inactive slots write block 0
+    unsharded: bool = False,
 ) -> tuple[jax.Array, dict]:
     """One-token attention over a paged KV pool (vLLM-style block tables).
 
@@ -165,9 +176,16 @@ def paged_attn_decode(
     positions ``[j*bs, (j+1)*bs)``.  Rows past a slot's allocation may point
     anywhere (conventionally block 0, the reserved garbage block) — their
     logical positions exceed ``pos`` so the causal mask hides them.  The new
-    token's KV is scattered into the pool *before* the gather, so position
+    token's KV is scattered into the pool *before* attention, so position
     ``pos`` itself is attended; inactive slots are redirected to block 0 so
     a retired slot can never corrupt blocks reallocated to a new request.
+
+    Attention then takes one of two paths, by :func:`paged_kernel_route`.
+    The Pallas kernel reads only each active slot's first ``pos + 1``
+    positions (an inactive slot reads nothing and yields zeros).  The gather
+    reads the whole block table of every slot and masks by position.
+    ``unsharded`` is static: ``serve_loop.build_decode_step`` sets it when
+    it builds the decode without a mesh, the one case the kernel serves.
     """
     if cfg.sliding_window is not None:
         raise ValueError("paged KV pool serves full-attention caches; "
@@ -193,26 +211,37 @@ def paged_attn_decode(
         cks = cache["k_scale"].at[phys, off].set(ks[:, 0])
         cvs = cache["v_scale"].at[phys, off].set(vs[:, 0])
         new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-        gk = layers.kv_dequantize(ck[block_table], cks[block_table], q.dtype)
-        gv = layers.kv_dequantize(cv[block_table], cvs[block_table], q.dtype)
     else:
         ck = cache["k"].at[phys, off].set(k[:, 0].astype(cache["k"].dtype))
         cv = cache["v"].at[phys, off].set(v[:, 0].astype(cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
-        gk = ck[block_table].astype(q.dtype)
-        gv = cv[block_table].astype(q.dtype)
-    # gathered view: (B, max_blocks, bs, ...) -> (B, max_blocks*bs, ...);
-    # entry j*bs+o sits at logical position j*bs+o by construction
-    skv = block_table.shape[1] * bs
-    gk = gk.reshape(B, skv, *gk.shape[3:])
-    gv = gv.reshape(B, skv, *gv.shape[3:])
-    out = layers.attention(
-        q, gk, gv,
-        causal=True,
-        q_offset=pos,
-        softcap=cfg.attn_logit_softcap,
-        kv_positions=jnp.arange(skv),
-    )
+    if paged_kernel_route(quant=quant, unsharded=unsharded):
+        from repro.kernels import ops as kernel_ops
+        lengths = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
+        out = kernel_ops.paged_attention(
+            q[:, 0], ck, cv, block_table, lengths,
+            softcap=cfg.attn_logit_softcap)
+    else:
+        if quant:
+            gk = layers.kv_dequantize(ck[block_table], cks[block_table],
+                                      q.dtype)
+            gv = layers.kv_dequantize(cv[block_table], cvs[block_table],
+                                      q.dtype)
+        else:
+            gk = ck[block_table].astype(q.dtype)
+            gv = cv[block_table].astype(q.dtype)
+        # gathered view: (B, max_blocks, bs, ...) -> (B, max_blocks*bs, ...);
+        # entry j*bs+o sits at logical position j*bs+o by construction
+        skv = block_table.shape[1] * bs
+        gk = gk.reshape(B, skv, *gk.shape[3:])
+        gv = gv.reshape(B, skv, *gv.shape[3:])
+        out = layers.attention(
+            q, gk, gv,
+            causal=True,
+            q_offset=pos,
+            softcap=cfg.attn_logit_softcap,
+            kv_positions=jnp.arange(skv),
+        )
     out = out.reshape(B, 1, -1) @ params["wo"]
     return x + out, new_cache
 

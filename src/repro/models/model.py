@@ -619,7 +619,8 @@ class Model:
     # ------------------------------------------------------------------
     # Decode
     # ------------------------------------------------------------------
-    def decode_step(self, params: dict, cache: dict, batch: dict) -> tuple[jax.Array, dict]:
+    def decode_step(self, params: dict, cache: dict, batch: dict, *,
+                    unsharded: bool = False) -> tuple[jax.Array, dict]:
         """One serving step: batch = {"token": (B, 1)} (+ "memory" for encdec).
 
         Serving-engine extensions (all optional, absent = training-era
@@ -629,6 +630,9 @@ class Model:
         (B, max_blocks) int32 switches full-attention KV families to the
         paged pool layout (``paged_cache_specs``), where inactive slots'
         writes are redirected to the reserved garbage block 0.
+        ``unsharded`` (static) says the step is built without a mesh, which
+        lets the paged pool take the Pallas kernel
+        (``blocks.paged_attn_decode``).
 
         Returns (logits (B, V), updated cache)."""
         cfg = self.cfg
@@ -645,7 +649,8 @@ class Model:
         def attn(ap, c, kvc):
             if bt is not None:
                 return blocks.paged_attn_decode(ap, c, kvc, bt, pos_t, cfg,
-                                                active=active)
+                                                active=active,
+                                                unsharded=unsharded)
             return blocks.self_attn_decode(ap, c, kvc, pos_t, cfg)
 
         if cfg.family == "moe" and cfg.moe_every > 1:

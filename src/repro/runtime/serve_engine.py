@@ -42,7 +42,10 @@ the tick's counters as attributes:
                       prefix included), ``bucket``, ``replay``
   serve.prefill       its prefill and the scatter into the cache (``rid``)
   serve.grow_blocks   block growth: ``blocks`` taken, ``evictions``
-  serve.decode        the decode step and the sampler's dispatch
+  serve.decode        the decode step and the sampler's dispatch: ``attn``
+                      (``paged_kernel`` or ``gather``, the path the paged
+                      pool's attention takes; None without a pool),
+                      ``kv_blocks`` (pool blocks the decoded slots attend)
   serve.wait          a host pull that waits on the device: ``for`` is
                       ``prefill`` (the first token) or ``decode``
   serve.bookkeep      token accounting, finishes and releases: ``finished``
@@ -59,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import telemetry as tel
+from repro.models import blocks
 from repro.models.common import Spec, init_params
 from repro.models.model import Model
 from repro.runtime import serve_loop
@@ -119,7 +123,11 @@ class ServeEngine:
         # would pollute it — these families prefill at exact prompt length
         self.exact_prefill = cfg.family in ("rwkv", "hybrid")
 
+        # the attention path the compiled decode takes over the paged pool
+        self.attn_route = None
         if self.paged:
+            self.attn_route = ("paged_kernel" if blocks.paged_kernel_route(
+                quant=cfg.kv_quant, unsharded=mesh is None) else "gather")
             self.block_size = block_size
             cap = cache_len + self.patch_off
             self.max_blocks = max_blocks or (cap // block_size + 1)
@@ -488,7 +496,10 @@ class ServeEngine:
                 evicted=self.n_evictions - evictions)
             if not active:
                 return []
-            with tel.span("serve.decode"):
+            kv_blocks = (sum(-(-(self.slots[i].pos + 1) // self.block_size)
+                             for i in active) if self.paged else None)
+            with tel.span("serve.decode", attn=self.attn_route,
+                          kv_blocks=kv_blocks):
                 mask = np.zeros(self.n_slots, bool)
                 mask[active] = True
                 tokens = np.array([s.next_token for s in self.slots],

@@ -55,9 +55,12 @@ def build_decode_step(model: Model, mesh: Mesh | None = None,
                       batch_specs: tuple[dict, dict] | None = None):
     """jit decode step; with a mesh, attaches explicit shardings + cache
     donation.  ``cache_specs`` / ``batch_specs`` override the default
-    per-slot cache tree and tick-batch shapes (serve-engine pool/batch)."""
+    per-slot cache tree and tick-batch shapes (serve-engine pool/batch).
+    Without a mesh the step is built ``unsharded``, so a paged pool may
+    take the Pallas decode kernel."""
     def decode_step(params, cache, batch_in):
-        return model.decode_step(params, cache, batch_in)
+        return model.decode_step(params, cache, batch_in,
+                                 unsharded=mesh is None)
 
     if mesh is None:
         return jax.jit(decode_step, donate_argnums=(1,))

@@ -54,7 +54,9 @@ def traced_run(dense):
     def watched(params, cache, batch):
         active = [i for i, s in enumerate(e.slots) if s.req is not None]
         assert int(np.asarray(batch["active"]).sum()) == len(active)
-        seen.append((len(active), sum(e.slots[i].pos + 1 for i in active)))
+        seen.append((len(active), sum(e.slots[i].pos + 1 for i in active),
+                     sum(-(-(e.slots[i].pos + 1) // e.block_size)
+                         for i in active)))
         return decode(params, cache, batch)
 
     e._decode = watched
@@ -104,7 +106,8 @@ def test_tick_counters_match_the_engine(traced_run):
     assert sum(g.attrs["evictions"] for g in named("serve.grow_blocks")) \
         == e.n_evictions
     assert [t.attrs["tick"] for t in ticks] == list(range(e.n_ticks))
-    assert [(t.attrs["live"], t.attrs["kv_positions"]) for t in ticks] == seen
+    assert [(t.attrs["live"], t.attrs["kv_positions"]) for t in ticks] == \
+        [(live, kv) for live, kv, _ in seen]
     assert all(t.attrs["n_slots"] == 2 and 0 <= t.attrs["free_blocks"] <= 6
                for t in ticks)
     # each admission gives one token, each live slot of a tick one more
@@ -118,6 +121,18 @@ def test_tick_counters_match_the_engine(traced_run):
     finished = sum(b.attrs["finished"] for b in named("serve.bookkeep"))
     assert finished == sum(r["finish_reason"] is not None
                            for r in e.results.values())
+
+
+def test_decode_span_names_its_route_and_blocks(traced_run):
+    """Each ``serve.decode`` names the attention path the compiled decode
+    takes (the gather on the CPU) and counts the pool blocks its slots
+    attend: ceil((pos + 1) / block_size) summed over the live slots."""
+    e, _, spans, seen = traced_run
+    decodes = [s for s in spans if s.name == "serve.decode"]
+    assert e.attn_route == "gather"
+    assert all(d.attrs["attn"] == "gather" for d in decodes)
+    assert [d.attrs["kv_blocks"] for d in decodes] == \
+        [blocks for _, _, blocks in seen]
 
 
 def _log(*spans):

@@ -63,7 +63,9 @@ NOINT = dict(interpret=False)
 # gpt-1.4b (the paper's Table I model): d 2112, 24 heads x 88, d_ff 8448,
 # vocab 51200; yi-6b: d 4096, 32 heads x 128 over 4 KV heads, d_ff 11008;
 # arctic-480b experts: d 7168, d_ff 4864; zamba2-2.7b mamba2: 80 heads x 64,
-# state 64; rwkv6-1.6b: 32 heads x 64
+# state 64; rwkv6-1.6b: 32 heads x 64.  The paged decode runs at the
+# closed32 serving cell's pool (32 slots, a 161-block table, 1600 blocks of
+# 16 positions) and at yi-6b's GQA heads over the same pool
 CASES = {
     "flash_hd88": (
         lambda q, k, v: ops.flash_attention(q, k, v, **NOINT),
@@ -121,6 +123,14 @@ CASES = {
                                                      **NOINT)[1],
         [((8, 32, 64), F32)] * 4 + [((32, 64), F32), ((8, 32, 64, 64), F32)],
         False),
+    "paged_decode_hd88": (
+        lambda q, k, v, t, n: ops.paged_attention(q, k, v, t, n, **NOINT),
+        [((32, 24, 88), BF16), ((1600, 16, 24, 88), BF16),
+         ((1600, 16, 24, 88), BF16), ((32, 161), I32), ((32,), I32)], False),
+    "paged_decode_gqa": (
+        lambda q, k, v, t, n: ops.paged_attention(q, k, v, t, n, **NOINT),
+        [((32, 32, 128), BF16), ((1600, 16, 4, 128), BF16),
+         ((1600, 16, 4, 128), BF16), ((32, 161), I32), ((32,), I32)], False),
 }
 
 
@@ -140,6 +150,8 @@ KERNEL_NAMES = {
     "wkv_scan": ("wkv_scan",),
     "mamba_decode_step": ("ssd_decode",),
     "wkv_decode_step": ("wkv_decode",),
+    "paged_decode_hd88": ("paged_decode",),
+    "paged_decode_gqa": ("paged_decode",),
 }
 
 
